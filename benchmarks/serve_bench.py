@@ -889,7 +889,10 @@ def bench_multicube(smoke: bool = False, seed: int = 0,
     from repro.models.common import AxisRules, DEFAULT_RULES
     from repro.serve import (AdmissionConfig, CacheConfig, CubeProcRouter,
                              EngineConfig, Request, ServeEngine)
+    from repro.serve.cube_proc import refuse_on_tpu
 
+    # before the in-process oracle engine takes the chip the workers need
+    refuse_on_tpu()
     size = size or ("smoke" if smoke else "full")
     n, max_new = {"smoke": (4, 6), "gate": (8, 8)}.get(size, (12, 10))
     arch = "qwen2.5-3b"

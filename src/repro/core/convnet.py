@@ -37,9 +37,8 @@ def init_params(
             continue
         key, wk = jax.random.split(key)
         fan_in = l.kx * l.ky * l.ci
-        w = jax.random.normal(wk, (l.kx, l.ky, l.ci, l.co), dtype) * np.sqrt(
-            2.0 / fan_in
-        ).astype(np.float32)
+        w = (jax.random.normal(wk, (l.kx, l.ky, l.ci, l.co), jnp.float32)
+             * np.sqrt(2.0 / fan_in)).astype(dtype)
         b = jnp.zeros((l.co,), dtype)
         params[l.name] = {"w": w, "b": b}
     return params

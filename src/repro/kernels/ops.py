@@ -74,7 +74,19 @@ def stream_mac_conv(
     block_yo: int = 8,
     interpret: bool | None = None,
 ):
-    """NHWC conv with HWIO weights (the paper's CONV layer)."""
+    """NHWC conv with HWIO weights (the paper's CONV layer).
+
+    Pads for the TPU's tiling: Ci and Co to the 128-lane tile, the output
+    width to the sublane tile (8 rows of 32-bit, 16 of bf16), and W to the
+    columns that width reads, rounded to the sublane tile too."""
+    interp = _interpret(interpret)
+    if stride != (1, 1) and x.dtype.itemsize != 4 and not interp:
+        # a strided tap is a strided VMEM load, which Mosaic implements for
+        # 32-bit data only ("Strided load with non 32-bit data")
+        raise NotImplementedError(
+            f"stream_mac_conv: stride {stride} compiles for TPU only with "
+            f"32-bit data, got {x.dtype}"
+        )
     n, h, wd, ci = x.shape
     kh, kw, _, co = w.shape
     sy, sx = stride
@@ -82,19 +94,23 @@ def stream_mac_conv(
     yo = (h + 2 * py - kh) // sy + 1
     wo = (wd + 2 * px - kw) // sx + 1
 
-    bci = _block(ci, 128)
-    bco = _block(co, 128)
+    sub = 8 * max(1, 4 // x.dtype.itemsize)
+    bci = 128
+    bco = 128
     byo = min(block_yo, yo)
     yo_p = yo + ((-yo) % byo)
+    wo_p = wo + ((-wo) % sub)
     h_need = (yo_p - 1) * sy + kh
+    w_need = (wo_p - 1) * sx + kw
+    w_need += (-w_need) % sub
 
-    xp = jnp.pad(x, ((0, 0), (py, max(py, h_need - h - py)), (px, px), (0, 0)))
-    xp = xp[:, :h_need]
-    xp = _pad_to(xp, 3, bci)
+    xp = jnp.pad(x, ((0, 0), (py, max(0, h_need - h - py)),
+                     (px, max(0, w_need - wd - px)), (0, 0)))
+    xp = _pad_to(xp[:, :h_need, :w_need], 3, bci)
     wp = _pad_to(_pad_to(w, 2, bci), 3, bco)
     out = _conv.stream_mac_conv(
         xp, wp, stride=stride, block_yo=byo, block_co=bco, block_ci=bci,
-        interpret=_interpret(interpret),
+        wo=wo_p, interpret=interp,
     )
     return out[:, :yo, :wo, :co]
 

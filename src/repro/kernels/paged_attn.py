@@ -47,23 +47,29 @@ def paged_gather(
     block_table: jax.Array,   # (B, pages_per_lane) int32, -1 = unallocated
     interpret: bool = False,
 ) -> jax.Array:
-    """(B, pages_per_lane, page_bytes) gather; -1 entries read as zeros."""
+    """(B, pages_per_lane, page_bytes) gather; -1 entries read as zeros.
+
+    Each page row travels as a (1, f) block whose two minor dims are the
+    array's own (a unit dim ahead of the row on both sides), so the block
+    obeys the TPU tiling rule without padding pages to 8 rows."""
     n_pages, f = pool.shape
     b, p = block_table.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, p),
         in_specs=[
-            pl.BlockSpec((1, f), lambda b_, p_, bt: (jnp.maximum(bt[b_, p_], 0), 0)),
+            pl.BlockSpec((1, 1, f),
+                         lambda b_, p_, bt: (jnp.maximum(bt[b_, p_], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda b_, p_, bt: (b_, p_, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, f), lambda b_, p_, bt: (b_, p_, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, p, f), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, p, 1, f), pool.dtype),
         interpret=interpret,
-    )(block_table, pool)
+    )(block_table, pool.reshape(n_pages, 1, f))
+    return out.reshape(b, p, f)
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,11 @@ Faithful structure (§IV-A / Fig 5):
 
 Hardware adaptation: the NST scalar MAC stream becomes a (rows×width, T_Ci)
 × (T_Ci, T_Co) MXU contraction per filter tap; the zig-zag layout becomes
-channels-minor NHWC so each tile's HBM window is contiguous per row.
+channels-minor NHWC so each tile's HBM window is contiguous per row.  Every
+tap's patch and weight slice is read from a VMEM *ref* (a shifted load, not a
+slice of a loaded value), and the patch is flattened to a 2-D matmul operand;
+``ops.stream_mac_conv`` pads W to the sublane tile and Ci/Co to the lane tile
+so those reads and the HBM window stay aligned.
 """
 from __future__ import annotations
 
@@ -31,7 +35,7 @@ def _conv_kernel(
     w_ref,            # (KH, KW, Ci, bco)       VMEM block (full Ci)
     o_ref,            # (1, byo, WO, bco)       VMEM output block
     x_spm,            # scratch: (bh_in, W_pad, bci)  — the "SPM" tile
-    acc_ref,          # scratch: (byo, WO, bco) f32   — resident partial sums
+    acc_ref,          # scratch: (byo * WO, bco) f32  — resident partial sums
     dma_sem,
     *,
     kh: int,
@@ -49,37 +53,29 @@ def _conv_kernel(
     y0 = yb * byo * sy
 
     def ci_pass(ci, _):
+        c0 = pl.multiple_of(ci * bci, bci)
         # --- cluster DMA: fetch one augmented input tile (with halo rows) ---
         copy = pltpu.make_async_copy(
-            x_hbm.at[n, pl.ds(y0, x_spm.shape[0]), :, pl.ds(ci * bci, bci)],
+            x_hbm.at[n, pl.ds(y0, x_spm.shape[0]), :, pl.ds(c0, bci)],
             x_spm,
             dma_sem,
         )
         copy.start()
         copy.wait()
-        xt = x_spm[...]
         # --- NST streams: (ky, kx) hardware loops around a T_Ci contraction -
         for dy in range(kh):
             for dx in range(kw):
-                patch = jax.lax.slice(
-                    xt,
-                    (dy, dx, 0),
-                    (dy + (byo - 1) * sy + 1, dx + (wo - 1) * sx + 1, bci),
-                    (sy, sx, 1),
-                )  # (byo, WO, bci)
-                wt = jax.lax.dynamic_slice_in_dim(
-                    w_ref[dy, dx], ci * bci, bci, axis=0
-                )  # (bci, bco)
-                acc_ref[...] += jax.lax.dot_general(
-                    patch,
-                    wt,
-                    (((2,), (0,)), ((), ())),
+                # (byo, WO, bci); a unit stride is a plain shifted load
+                patch = x_spm[pl.ds(dy, byo, sy), pl.ds(dx, wo, sx), :]
+                wt = w_ref[dy, dx, pl.ds(c0, bci), :]         # (bci, bco)
+                acc_ref[...] += jnp.dot(
+                    patch.reshape(byo * wo, bci), wt,
                     preferred_element_type=jnp.float32,
                 )
         return 0
 
     jax.lax.fori_loop(0, n_ci, ci_pass, 0)
-    o_ref[...] = acc_ref[...][None].astype(o_ref.dtype)
+    o_ref[...] = acc_ref[...].reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def stream_mac_conv(
@@ -89,16 +85,20 @@ def stream_mac_conv(
     block_yo: int = 8,
     block_co: int = 128,
     block_ci: int = 128,
+    wo: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Valid conv over a pre-padded input.  Ci % block_ci == 0, Co % block_co
-    == 0, and YO % block_yo == 0 are required (``ops.stream_mac_conv`` pads)."""
+    == 0, and YO % block_yo == 0 are required (``ops.stream_mac_conv`` pads).
+    ``wo`` (default: every valid column) lets the wrapper compute a
+    sublane-aligned output width from a W padded to match."""
     n, h, wdt, ci = x.shape
     kh, kw, ci2, co = w.shape
     assert ci == ci2
     sy, sx = stride
     yo = (h - kh) // sy + 1
-    wo = (wdt - kw) // sx + 1
+    wo = (wdt - kw) // sx + 1 if wo is None else wo
+    assert (wo - 1) * sx + kw <= wdt
     assert yo % block_yo == 0 and co % block_co == 0 and ci % block_ci == 0
     n_ci = ci // block_ci
     bh_in = (block_yo - 1) * sy + kh
@@ -111,7 +111,7 @@ def stream_mac_conv(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),             # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),               # x stays in HBM
             pl.BlockSpec((kh, kw, ci, block_co), lambda n_, y_, c_: (0, 0, 0, c_)),
         ],
         out_specs=pl.BlockSpec(
@@ -120,7 +120,7 @@ def stream_mac_conv(
         out_shape=jax.ShapeDtypeStruct((n, yo, wo, co), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((bh_in, wdt, block_ci), x.dtype),
-            pltpu.VMEM((block_yo, wo, block_co), jnp.float32),
+            pltpu.VMEM((block_yo * wo, block_co), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,

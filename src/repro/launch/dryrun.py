@@ -49,7 +49,7 @@ from repro.dist.sharding import (
     replicated,
     tree_shardings,
 )
-from repro.launch.mesh import describe, make_production_mesh, set_mesh
+from repro.launch.mesh import describe, make_production_mesh
 from repro.models.api import build_model, input_specs
 from repro.models.common import abstract_params
 from repro.optim.optimizer import adamw
@@ -120,7 +120,7 @@ def lower_cell(
     label = f"{arch}/{shape_name}/{describe(mesh)}{label_suffix}"
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if step_kind == "train":
             opt = adamw(state_dtype=jnp.dtype(cfg.opt_state_dtype))
             ostate = jax.eval_shape(opt.init, aparams)

@@ -12,25 +12,25 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """Context for entering ``mesh``: ``jax.set_mesh`` on jax ≥ 0.6, else the
-    Mesh object itself (a context manager on older jax)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    # Auto axes: the models' logical-axis constraints are sharding hints the
+    # compiler may propagate, not the asserts they become on Explicit axes
+    # (``jax.make_mesh``'s default)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Mesh over whatever devices exist (CPU tests: usually 1)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def describe(mesh) -> str:

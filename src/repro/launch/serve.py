@@ -1,11 +1,13 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Reduced config on CPU; the production mesh path is proven by the dry-run's
-prefill/decode cells.  ``--cubes N`` routes requests across N cube-replica
-engines (``serve.router.CubeRouter``); the scheduler/paged-cache knobs mirror
-``serve.engine.EngineConfig``.
+Serves the arch's reduced config by default; ``--full`` serves its published
+widths (one v5e chip holds qwen2.5-3b: ``--full --attn-impl pallas``).
+``--cubes N`` routes requests across N cube-replica engines
+(``serve.router.CubeRouter``), one per device when the host has N devices;
+the scheduler/paged-cache knobs mirror ``serve.engine.EngineConfig``.
 """
 import argparse
+import contextlib
 import json
 import time
 
@@ -14,7 +16,8 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.dist.sharding import arch_rules
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.serve import (
     AdmissionConfig,
@@ -31,6 +34,12 @@ from repro.serve import (
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the arch at its published widths instead of "
+                         "the reduced CPU config")
+    ap.add_argument("--attn-impl", choices=["xla", "pallas"], default="xla",
+                    help="paged decode attention: XLA transient gather, or "
+                         "the fused Pallas kernel (interpret mode off-TPU)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -85,11 +94,10 @@ def main(argv=None):
                          "ui.perfetto.dev)")
     args = ap.parse_args(argv)
 
-    cfg = get_arch(args.arch).reduced()
-    mesh = make_host_mesh()
-    rules = arch_rules(cfg, mesh, step="decode", global_batch=args.slots)
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
     ecfg = EngineConfig(
         batch_slots=args.slots, max_len=args.max_len,
         cache=CacheConfig(
@@ -98,6 +106,7 @@ def main(argv=None):
             host_pages=args.host_pages or None,
             swap_token_cost=args.swap_cost,
             prefix_sharing=args.prefix_sharing,
+            attn_impl=args.attn_impl,
         ),
         admission=AdmissionConfig(
             policy=args.policy, prefill_chunk=args.prefill_chunk,
@@ -107,16 +116,29 @@ def main(argv=None):
         ),
         obs=ObsConfig(trace=args.trace is not None),
     )
-    with set_mesh(mesh):
-        if args.cubes > 1 and args.multiproc:
-            eng = CubeProcRouter(args.arch, ecfg, n_cubes=args.cubes,
-                                 policy=args.route,
-                                 checkpoint_every=args.checkpoint_every)
-        elif args.cubes > 1:
-            eng = CubeRouter(model, params, ecfg, n_cubes=args.cubes,
-                             policy=args.route)
+    mesh_ctx = contextlib.nullcontext()
+    if args.cubes > 1 and args.multiproc:
+        # the workers build their own (reduced) model; the parent stays off
+        # the device, and CubeProcRouter refuses to start on a TPU host
+        eng = CubeProcRouter(args.arch, ecfg, n_cubes=args.cubes,
+                             policy=args.route,
+                             checkpoint_every=args.checkpoint_every)
+    else:
+        model = build_model(cfg)
+        params = model.init(jax.random.key(0))
+        if args.cubes > 1:
+            devs = jax.devices()
+            eng = CubeRouter(
+                model, params, ecfg, n_cubes=args.cubes, policy=args.route,
+                devices=devs[:args.cubes] if len(devs) >= args.cubes else None,
+            )
         else:
+            mesh = make_host_mesh()
+            mesh_ctx = jax.set_mesh(mesh)
+            rules = arch_rules(cfg, mesh, step="decode",
+                               global_batch=args.slots)
             eng = ServeEngine(model, params, ecfg, rules)
+    with mesh_ctx:
         rng = np.random.default_rng(0)
         for i in range(args.requests):
             eng.submit(Request(

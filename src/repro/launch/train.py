@@ -15,11 +15,11 @@ from repro.configs import get_arch
 from repro.data.pipeline import SyntheticLMData
 from repro.dist.collectives import overlap_flags
 from repro.dist.sharding import arch_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (
     describe,
     make_host_mesh,
     make_production_mesh,
-    set_mesh,
 )
 from repro.models import build_model
 from repro.train.trainer import Trainer, TrainerConfig
@@ -39,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--overlap", default="aggressive")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.overlap == "aggressive" and jax.default_backend() == "tpu":
         flags = " ".join(f"--{k}={v}" for k, v in overlap_flags().items())
         os.environ["LIBTPU_INIT_ARGS"] = flags
@@ -58,7 +59,7 @@ def main(argv=None):
     )
     print(f"training {cfg.name} on mesh [{describe(mesh)}] "
           f"for {args.steps} steps")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         tr = Trainer(model, data, tcfg, rules)
         state, restarts = tr.run_with_restarts(jax.random.key(0))
     first = sum(state.losses[:10]) / max(len(state.losses[:10]), 1)

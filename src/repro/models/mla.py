@@ -126,8 +126,11 @@ def _absorbed_attend(cfg, p, q_nope, q_rope, latent, k_rope, mask):
     s = (s_lat + s_rope) * scale                          # (B,H,S,T)
     s = jnp.where(mask, s, -1e30)
     a = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhst,btr->bshr", a.astype(latent.dtype), latent,
-                     preferred_element_type=jnp.float32)
+    # contract into (B,H,S,rank) and transpose after: XLA:CPU has no bf16
+    # x bf16 -> f32 dot for the direct "bhst,btr->bshr" layout; the MXU
+    # sees the same bf16 operands and f32 accumulation either way
+    ctx = jnp.einsum("bhst,btr->bhsr", a.astype(latent.dtype), latent,
+                     preferred_element_type=jnp.float32).transpose(0, 2, 1, 3)
     wv_b = p["wv_b"].reshape(rank, h, vd)
     return jnp.einsum("bshr,rhv->bshv", ctx.astype(wv_b.dtype), wv_b,
                       preferred_element_type=jnp.float32)

@@ -73,10 +73,25 @@ from repro.dist.fault import StragglerDetector
 from repro.obs import clock as obs_clock
 from repro.obs.wire import unwire_snapshot, wire_snapshot
 
-__all__ = ["CubeProc", "CubeProcRouter", "worker_main",
+__all__ = ["CubeProc", "CubeProcRouter", "refuse_on_tpu", "worker_main",
            "send_frame", "recv_frame", "pack_payload", "unpack_payload"]
 
 _SRC = pathlib.Path(__file__).resolve().parents[2]
+
+
+def refuse_on_tpu() -> None:
+    """Raise when JAX's backend is a TPU.  A TPU chip belongs to one process
+    at a time, so worker processes that each need the chip would fail or
+    hang behind the parent; in-process replicas over the host's devices
+    (``CubeRouter(devices=jax.devices())``) are the TPU layout."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "CubeProcRouter starts one JAX process per cube, but a TPU chip "
+            "belongs to one process: serve the cubes in-process with "
+            "CubeRouter(devices=jax.devices()) instead"
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -407,6 +422,7 @@ class CubeProcRouter:
                  startup_timeout: float = 300.0):
         if policy not in ("hash", "least_loaded", "prefix_affinity"):
             raise ValueError(f"unknown router policy: {policy!r}")
+        refuse_on_tpu()
         self.arch = arch
         self.policy = "least_loaded" if policy == "prefix_affinity" else policy
         self.axis = CUBE_AXIS            # telemetry keys match CubeRouter

@@ -255,7 +255,12 @@ class ServeEngine:
     """Greedy/temperature sampling over the DecoderLM serving API, backed by
     a paged KV cache, a request scheduler, and an admission pipeline."""
 
-    def __init__(self, model, params, ecfg: EngineConfig, rules=None):
+    def __init__(self, model, params, ecfg: EngineConfig, rules=None,
+                 device=None):
+        """``device`` pins the engine to one device: the params are copied
+        there and the page pools made there, so every jitted step runs
+        there too (one replica per chip behind ``CubeRouter``).  None keeps
+        JAX's default placement."""
         if ecfg.decode_path not in ("paged", "gather"):
             raise ValueError(f"unknown decode_path: {ecfg.decode_path!r}")
         if ecfg.preempt_policy not in ("swap", "recompute"):
@@ -269,6 +274,8 @@ class ServeEngine:
                 f"{type(model).__name__} has no decode_step_paged; serve it "
                 "with decode_path='gather'"
             )
+        if device is not None:
+            params = jax.device_put(params, device)
         self.model = model
         self.params = params
         self.ecfg = ecfg
@@ -308,6 +315,7 @@ class ServeEngine:
             model, lanes=ecfg.batch_slots, n_pages=n_pages, page_size=ps,
             max_len=ecfg.max_len, host_pages=host_pages,
             metrics=self.metrics, prefix_sharing=ecfg.prefix_sharing,
+            device=device,
         )
         chunk = (ecfg.prefill_chunk
                  if getattr(model, "supports_chunked_prefill", False) else 0)
@@ -976,22 +984,30 @@ class ServeEngine:
                 cache.fork_pages(copies)
 
     @decode_loop_only
-    @pool_mutator("pools")
-    def _decode_lanes(self, key):
-        s, b = self.sched, self.ecfg.batch_slots
+    def decode_inputs(self):
+        """Host arrays of the next decode step: (block_tables, tokens (B,1),
+        positions (B,), active (B,)) — what ``_decode_lanes`` feeds the
+        jitted step, for callers that replay that step against the pools."""
+        b = self.ecfg.batch_slots
         tokens = np.zeros((b, 1), np.int32)
         positions = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)
-        for lane, st in s.running.items():
+        for lane, st in self.sched.running.items():
             tokens[lane, 0] = st.pending_token
             positions[lane] = st.length
             active[lane] = True
+        return self.cache.block_tables.copy(), tokens, positions, active
+
+    @decode_loop_only
+    @pool_mutator("pools")
+    def _decode_lanes(self, key):
+        s = self.sched
+        bt, tokens, positions, active = self.decode_inputs()
         n_active = int(active.sum())
         self.tracer.begin(self.tracer.EV_DECODE, n_active)
         with self._annot():
             logits, self.cache.pools = self._decode(
-                self.params, self.cache.pools,
-                jnp.asarray(self.cache.block_tables),
+                self.params, self.cache.pools, jnp.asarray(bt),
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(active),
             )

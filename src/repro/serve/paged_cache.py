@@ -631,12 +631,13 @@ class PagedKVCache:
     swaps victim pages out to instead of freeing them — see ``swap_out`` /
     ``swap_in``.  ``host_shardings`` optionally carries a replicated
     ``NamedSharding`` tree (``dist.sharding.host_tier_shardings``) for the
-    ``device_put`` staging on a mesh.
+    ``device_put`` staging on a mesh.  ``device`` places the pools (None:
+    JAX's default device).
     """
 
     def __init__(self, model, lanes: int, n_pages: int, page_size: int,
                  max_len: int, host_pages: int = 0, host_shardings=None,
-                 metrics=None, prefix_sharing: bool = False):
+                 metrics=None, prefix_sharing: bool = False, device=None):
         if not hasattr(model, "cache_page_specs"):
             raise TypeError(
                 f"{type(model).__name__} has no paged-cache layout "
@@ -650,7 +651,7 @@ class PagedKVCache:
         self.capacity = self.pages_per_lane * page_size   # per-lane view len
         specs = model.cache_page_specs(lanes, n_pages, page_size)
         self.pools = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), specs
+            lambda s: jnp.zeros(s.shape, s.dtype, device=device), specs
         )
         self.allocator = PageAllocator(n_pages)
         self.block_tables = np.full((lanes, self.pages_per_lane), -1, np.int32)

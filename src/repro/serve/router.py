@@ -15,9 +15,12 @@ spread by
   traffic landing where its KV pages already live — only useful with
   ``CacheConfig.prefix_sharing`` on.
 
-On the 1-device CPU test host every cube's sharding degrades to replication
-via ``dist.sharding.cube_rules``; the routing logic and telemetry are
-identical to the multi-cube layout.
+``devices`` spreads the cubes over real devices: one engine per device,
+each with its own copy of the params, its own page pools and its own jitted
+steps there — four one-chip replicas in one process on a four-chip host.
+Without it every engine shares JAX's default device (the 1-device CPU test
+host, where each cube's sharding degrades to replication via
+``dist.sharding.cube_rules``); the routing logic and telemetry are the same.
 """
 from __future__ import annotations
 
@@ -31,9 +34,18 @@ class CubeRouter:
     """Hash / least-loaded routing of requests over per-cube engines."""
 
     def __init__(self, model, params, ecfg: EngineConfig, n_cubes: int = 2,
-                 policy: str = "least_loaded", rules=None, mesh=None):
+                 policy: str = "least_loaded", rules=None, mesh=None,
+                 devices=None):
         if policy not in ("hash", "least_loaded", "prefix_affinity"):
             raise ValueError(f"unknown router policy: {policy!r}")
+        if devices is not None:
+            devices = list(devices)
+            if len(devices) != n_cubes:
+                raise ValueError(
+                    f"{n_cubes} cubes need {n_cubes} devices, got "
+                    f"{len(devices)}")
+            if len(set(devices)) != n_cubes:
+                raise ValueError("each cube needs a device of its own")
         if rules is None:
             from repro.dist.sharding import cube_rules
 
@@ -43,7 +55,9 @@ class CubeRouter:
         self.policy = policy
         self.axis = CUBE_AXIS
         self.engines = [
-            ServeEngine(model, params, ecfg, rules) for _ in range(n_cubes)
+            ServeEngine(model, params, ecfg, rules,
+                        device=None if devices is None else devices[i])
+            for i in range(n_cubes)
         ]
         # routing counters get their own registry (the router has no lock
         # of its own to share); per-cube keys "routed.<axis><i>"

@@ -112,7 +112,8 @@ SUBPROC = textwrap.dedent("""
     import json, sys
     import jax
     from repro.launch import dryrun
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     out = {}
     for arch, shape in [("qwen2.5-3b", "train_4k"), ("mamba2-130m", "long_500k"),
                         ("qwen3-moe-235b-a22b", "decode_32k")]:
@@ -131,7 +132,9 @@ SUBPROC = textwrap.dedent("""
 def test_dryrun_subprocess_multipod():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("JAX_PLATFORMS", None)
+    # the forced host device count applies to the CPU backend only; keep the
+    # child on it so the dry-run never loads an accelerator runtime
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", SUBPROC], env=env, capture_output=True,
         text=True, timeout=900,
