@@ -10,9 +10,10 @@ Three layers, strictly ordered by cost:
   single (shareable) lock, snapshot-consistent, wire-serializable via
   :mod:`repro.obs.wire`.
 
-`repro.obs.wire` is the only submodule that imports jax; keep it that
-way so the tracer and metrics stay importable (and cheap) everywhere,
-including under the engine lock.
+`repro.obs.wire` is the only submodule that imports jax at import time
+(a tracer built with ``annotate=True`` imports ``jax.profiler`` then);
+keep it that way so the tracer and metrics stay importable (and cheap)
+everywhere, including under the engine lock.
 """
 from .clock import ManualClock, monotonic, reset_source, set_source
 from .metrics import (

@@ -11,8 +11,16 @@ every function marked :func:`hot_path`):
   ``next(self._seq)`` — a single CPython bytecode on an ``itertools.count``,
   atomic under the GIL — then written without coordination.  Two threads
   never share a slot; a reader only runs after recording stops.
-- **No jax on the hot path.**  Timestamps come from :mod:`repro.obs.clock`
-  (host monotonic time); device timing stays in the benches.
+- **No jax in the ring.**  Timestamps come from :mod:`repro.obs.clock`
+  (host monotonic time); device time comes from the profiler sink below.
+
+A span (``begin``/``end``) feeds two sinks from one call: the ring, when
+the tracer is ``enabled``, and — when it is built with ``annotate=True`` —
+a ``jax.profiler.TraceAnnotation`` named ``"repro." + <event name>``, which
+lands in the profiler's trace beside the device's ops and on its clock.
+The profiler sink is opt-in and lives in two unmarked helpers (it builds
+one profiler object per span); with both sinks off a span site is one flag
+test.
 
 When the buffer wraps, the oldest events are overwritten and counted as
 ``dropped`` — tracing degrades by forgetting history, never by blocking
@@ -68,11 +76,20 @@ class Tracer:
     whose ``next()`` is atomic under the GIL.
     """
 
-    def __init__(self, capacity: int = 1 << 15, enabled: bool = True):
+    def __init__(self, capacity: int = 1 << 15, enabled: bool = True,
+                 annotate: bool = False):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self._on = bool(enabled)
+        # profiler sink: spans also open a TraceAnnotation on the calling
+        # thread's stack of open spans (jax is imported only when asked for)
+        self._annotate = bool(annotate)
+        self._live = self._on or self._annotate
+        self._open = threading.local()
+        if self._annotate:
+            from jax.profiler import TraceAnnotation
+            self._span_cls = TraceAnnotation
         self._seq = itertools.count()
         self._ts = np.zeros(self.capacity, np.float64)
         self._tid = np.zeros(self.capacity, np.int64)
@@ -85,6 +102,7 @@ class Tracer:
         self._sn = np.full(self.capacity, -1, np.int64)
         # Event schema: id -> (name, argnames).  Registration is cold.
         self._names: list[str] = []
+        self._span_names: list[str] = []    # "repro." + name, the profiler's
         self._argnames: list[tuple[str, ...]] = []
         self._reg_lock = threading.Lock()
         self._thread_names: dict[int, str] = {}
@@ -95,6 +113,7 @@ class Tracer:
         """Register an event type; returns the integer id hot paths use."""
         with self._reg_lock:
             self._names.append(str(name))
+            self._span_names.append("repro." + str(name))
             self._argnames.append(tuple(argnames))
             return len(self._names) - 1
 
@@ -113,13 +132,34 @@ class Tracer:
 
     @property
     def enabled(self) -> bool:
+        """Whether the ring records (the profiler sink is fixed at build)."""
         return self._on
 
     def enable(self) -> None:
         self._on = True
+        self._live = True
 
     def disable(self) -> None:
         self._on = False
+        self._live = self._annotate
+
+    # -- profiler sink (only reached with annotate=True) -----------------
+
+    def _span_open(self, ev: int) -> None:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        stack.append((ev, self._span_cls(self._span_names[ev])))
+
+    def _span_close(self, ev: int) -> None:
+        """Close this thread's innermost open span of ``ev``, and any span
+        still open inside it (one whose ``end`` an exception skipped)."""
+        stack = getattr(self._open, "stack", None)
+        while stack:
+            open_ev, span = stack.pop()
+            span.__exit__(None, None, None)
+            if open_ev == ev:
+                return
 
     # -- hot path: recording --------------------------------------------
 
@@ -139,10 +179,18 @@ class Tracer:
 
     @hot_path
     def begin(self, ev: int, a0: int = NOARG, a1: int = NOARG) -> None:
+        if not self._live:
+            return
         self._record(ev, PH_BEGIN, a0, a1)
+        if self._annotate:
+            self._span_open(ev)
 
     @hot_path
     def end(self, ev: int, a0: int = NOARG, a1: int = NOARG) -> None:
+        if not self._live:
+            return
+        if self._annotate:
+            self._span_close(ev)
         self._record(ev, PH_END, a0, a1)
 
     @hot_path
@@ -215,11 +263,24 @@ class ServeTracer(Tracer):
     PHASES = ("waiting", "match", "prefill", "restore", "ready", "running",
               "done")
 
-    def __init__(self, capacity: int = 1 << 15, enabled: bool = True):
-        super().__init__(capacity=capacity, enabled=enabled)
+    def __init__(self, capacity: int = 1 << 15, enabled: bool = True,
+                 annotate: bool = False):
+        super().__init__(capacity=capacity, enabled=enabled,
+                         annotate=annotate)
         self.EV_STEP = self.register("engine.step", ("step",))
+        # the decode loop's step, in order: lane fill, page checks, then
+        # the decode batch — its inputs' upload, the program's enqueue, the
+        # wait for its logits, their copy to the host, the per-lane choice
+        self.EV_LANES_FILL = self.register("lanes.fill", ())
+        self.EV_PAGES_ENSURE = self.register("pages.ensure", ())
         self.EV_DECODE = self.register("decode.batch", ("lanes",))
+        self.EV_DECODE_INPUTS = self.register("decode.inputs", ())
+        self.EV_DECODE_DISPATCH = self.register("decode.dispatch", ())
+        self.EV_DECODE_WAIT = self.register("decode.wait", ())
+        self.EV_DECODE_FETCH = self.register("decode.fetch", ())
+        self.EV_DECODE_SAMPLE = self.register("decode.sample", ())
         self.EV_PREFILL_CHUNK = self.register("prefill.chunk", ("uid", "tokens"))
+        self.EV_PREFILL_SAMPLE = self.register("prefill.sample", ("uid",))
         self.EV_STAGE_IN = self.register("swap_in.stage", ("uid", "pages"))
         self.EV_SWAP_OUT = self.register("swap_out.batch", ("victims", "pages"))
         self.EV_ADMIT = self.register("admission.reserve", ("uid", "pages"))
@@ -245,9 +306,10 @@ class ServeTracer(Tracer):
         self._record(ev, PH_INSTANT, uid, NOARG)
 
 
-# Shared disabled tracer: the default for every serve-layer tracer slot,
-# so call sites never branch on "is tracing on".  capacity=1 keeps the
-# idle footprint at seven scalars.
+# Shared disabled tracer, both sinks off: the default for every serve-layer
+# tracer slot, so call sites never branch on "is tracing on".  It is never
+# switched on — an engine that wants either sink builds its own.
+# capacity=1 keeps the idle footprint at seven scalars.
 NULL_TRACER = ServeTracer(capacity=1, enabled=False)
 
 __all__ = [

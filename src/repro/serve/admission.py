@@ -96,7 +96,11 @@ class AdmissionPipeline:
         tr = eng.tracer
         tr.begin(tr.EV_PREFILL_CHUNK, st.req.uid, chunk)
         done = eng.run_prefill(st, chunk)
-        tok = eng.sample_prefill_token(st) if done else None
+        tok = None
+        if done:
+            tr.begin(tr.EV_PREFILL_SAMPLE, st.req.uid)
+            tok = eng.sample_prefill_token(st)
+            tr.end(tr.EV_PREFILL_SAMPLE, st.req.uid)
         tr.end(tr.EV_PREFILL_CHUNK, st.req.uid)
         with eng._lock:
             self._c["chunks_run"].inc()
@@ -174,28 +178,34 @@ class AdmissionPipeline:
 
     def kick(self) -> None:
         """Ensure the worker thread is running (started lazily on submit,
-        parked again when the engine drains)."""
+        parked again when the engine drains).  The check and the start are
+        one step under the engine lock: a client thread's ``submit`` and
+        the decode loop may kick at once, and only one worker may run."""
         if not self.async_mode:
             return
-        if self._thread is None or not self._thread.is_alive():
-            self._stop = False
-            self._thread = threading.Thread(
-                target=self._worker, daemon=True,
-                name="serve-admission-pipeline",
-            )
-            self._thread.start()
+        with self.engine._lock:
+            if self._thread is None or not self._thread.is_alive():
+                self._stop = False
+                self._thread = threading.Thread(
+                    target=self._worker, daemon=True,
+                    name="serve-admission-pipeline",
+                )
+                self._thread.start()
 
     def shutdown(self) -> None:
         """Stop and join the worker (idempotent; engine idle or teardown)."""
-        t = self._thread
-        if t is None:
-            return
         with self.engine._lock:
+            t = self._thread
+            if t is None:
+                return
             self._stop = True
             self.engine._cv.notify_all()
         if t.is_alive():
             t.join(timeout=10)
-        self._thread = None
+        with self.engine._lock:
+            # a kick during the join may have started a successor: keep it
+            if self._thread is t:
+                self._thread = None
 
     @admission_api
     def _select(self):

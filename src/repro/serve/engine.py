@@ -34,7 +34,6 @@ import dataclasses
 import threading
 import warnings
 from dataclasses import dataclass, field
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -143,8 +142,12 @@ class ObsConfig:
     # disabled-flag check through the shared NULL_TRACER
     trace: bool = False
     trace_capacity: int = 1 << 15   # ring slots; wraparound drops oldest
-    # wrap each compiled decode step in a jax.profiler.TraceAnnotation so
-    # device profiles (XLA/TPU) line up with the host-side obs trace
+    # trace_annotations=True opens every span of the trace's schema (step,
+    # lane fill, page checks, the decode batch's inputs, dispatch, wait,
+    # fetch and sampling, prefill chunks) also as a jax.profiler
+    # TraceAnnotation named "repro." + the event name: the same names land
+    # in a device profile, on the device trace's clock.  Independent of
+    # `trace`: either sink works without the other
     trace_annotations: bool = False
 
 
@@ -292,14 +295,11 @@ class ServeEngine:
         self._cv = threading.Condition(self._lock)
         self.metrics = MetricsRegistry(lock=self._lock)
         self.tracer: ServeTracer = (
-            ServeTracer(capacity=ecfg.trace_capacity) if ecfg.trace
-            else NULL_TRACER
+            ServeTracer(capacity=ecfg.trace_capacity if ecfg.trace else 1,
+                        enabled=ecfg.trace,
+                        annotate=ecfg.trace_annotations)
+            if ecfg.trace or ecfg.trace_annotations else NULL_TRACER
         )
-        if ecfg.trace_annotations:
-            self._annot: Any = lambda: jax.profiler.TraceAnnotation(
-                "repro.decode_step")
-        else:
-            self._annot = contextlib.nullcontext
         ps = ecfg.page_size
         n_pages = (
             ecfg.n_pages
@@ -341,6 +341,11 @@ class ServeEngine:
         self._g_occ = m.gauge("occupancy")
         self._h_step = m.histogram("step_latency_s")
         self._h_queue = m.histogram("queue_wait_s")
+        # the decode step in three: step start to the program's enqueue,
+        # the host blocked on the device, then logits fetch and sampling
+        self._h_prep = m.histogram("decode.prep_s")
+        self._h_wait = m.histogram("decode.wait_s")
+        self._h_sample = m.histogram("decode.sample_s")
         sanitizer.register_engine(self)
         self.pipeline = AdmissionPipeline(self, ecfg.async_prefill)
         self._idle_since: float | None = None
@@ -364,9 +369,7 @@ class ServeEngine:
         self._extend = jax.jit(self._extend_impl, donate_argnums=(1,))
         # whole-prompt prefill, jit-cached per prompt length (the dense v1
         # engine ran this eagerly — measured prefill-bound on mixed traffic)
-        self._prefill = jax.jit(
-            lambda params, toks: self.model.prefill(params, toks, self.rules)
-        )
+        self._prefill = jax.jit(self._prefill_impl)
 
     def __del__(self):
         with contextlib.suppress(Exception):
@@ -392,6 +395,9 @@ class ServeEngine:
             params, pools, bt, tokens, positions, active, self.rules,
             attn_impl=self.ecfg.attn_impl,
         )
+
+    def _prefill_impl(self, params, tokens):
+        return self.model.prefill(params, tokens, self.rules)
 
     def _extend_impl(self, params, tree, tokens, start):
         # one chunked-prefill step over a request's PRIVATE cache tree —
@@ -1000,19 +1006,30 @@ class ServeEngine:
 
     @decode_loop_only
     @pool_mutator("pools")
-    def _decode_lanes(self, key):
-        s = self.sched
+    def _decode_lanes(self, key, t_step: float):
+        """One batched decode step over the running lanes; ``t_step`` is
+        when the engine step began (the ``decode.prep_s`` origin)."""
+        s, tr = self.sched, self.tracer
+        n_active = len(s.running)
+        tr.begin(tr.EV_DECODE, n_active)
+        tr.begin(tr.EV_DECODE_INPUTS)
         bt, tokens, positions, active = self.decode_inputs()
-        n_active = int(active.sum())
-        self.tracer.begin(self.tracer.EV_DECODE, n_active)
-        with self._annot():
-            logits, self.cache.pools = self._decode(
-                self.params, self.cache.pools, jnp.asarray(bt),
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(active),
-            )
-        logits = np.asarray(logits[:, 0], np.float32)
-        self.tracer.end(self.tracer.EV_DECODE, n_active)
+        inputs = (jnp.asarray(bt), jnp.asarray(tokens),
+                  jnp.asarray(positions), jnp.asarray(active))
+        tr.end(tr.EV_DECODE_INPUTS)
+        tr.begin(tr.EV_DECODE_DISPATCH)
+        logits, self.cache.pools = self._decode(
+            self.params, self.cache.pools, *inputs)
+        tr.end(tr.EV_DECODE_DISPATCH)
+        t_sent = obs_clock.monotonic()
+        tr.begin(tr.EV_DECODE_WAIT)
+        logits = logits[:, 0].block_until_ready()
+        tr.end(tr.EV_DECODE_WAIT)
+        t_ready = obs_clock.monotonic()
+        tr.begin(tr.EV_DECODE_FETCH)
+        logits = np.asarray(logits, np.float32)
+        tr.end(tr.EV_DECODE_FETCH)
+        tr.begin(tr.EV_DECODE_SAMPLE)
         done = 0
         for lane in sorted(list(s.running)):
             st = s.running[lane]
@@ -1037,9 +1054,15 @@ class ServeEngine:
                 or st.length >= self.ecfg.max_len - 1
             ):
                 self._retire_lane(st)
+        tr.end(tr.EV_DECODE_SAMPLE)
+        tr.end(tr.EV_DECODE, n_active)
+        t_done = obs_clock.monotonic()
         with self._lock:
             self._c_decode.inc(done)
             self._c_lane_step.inc(n_active)
+            self._h_prep.observe(t_sent - t_step)
+            self._h_wait.observe(t_ready - t_sent)
+            self._h_sample.observe(t_done - t_ready)
 
     # -- step loop -------------------------------------------------------------
 
@@ -1054,14 +1077,14 @@ class ServeEngine:
         t0 = obs_clock.monotonic()
         self.tracer.begin(self.tracer.EV_STEP)
         try:
-            return self._step_inner(key)
+            return self._step_inner(key, t0)
         finally:
             self.tracer.end(self.tracer.EV_STEP)
             with self._lock:
                 self._h_step.observe(obs_clock.monotonic() - t0)
 
     @decode_loop_only
-    def _step_inner(self, key=None) -> bool:
+    def _step_inner(self, key, t0: float) -> bool:
         if self.pipeline.error is not None:
             err, self.pipeline.error = self.pipeline.error, None
             raise RuntimeError("admission pipeline died") from err
@@ -1085,11 +1108,16 @@ class ServeEngine:
             progressed = False
         else:
             progressed = self.pipeline.pump(budget)
+        tr = self.tracer
+        tr.begin(tr.EV_LANES_FILL)
         progressed = self._fill_lanes() or progressed
+        tr.end(tr.EV_LANES_FILL)
         if s.running:
+            tr.begin(tr.EV_PAGES_ENSURE)
             self._ensure_pages()
+            tr.end(tr.EV_PAGES_ENSURE)
             if s.running:        # _ensure_pages may have evicted every lane
-                self._decode_lanes(key)
+                self._decode_lanes(key, t0)
             progressed = True
         with self._lock:
             self._c_steps.inc()

@@ -15,6 +15,8 @@ The load-bearing invariants:
   pipeline crash surfaces in the decode loop instead of hanging it.
 """
 import dataclasses
+import threading
+import time
 
 import jax
 import numpy as np
@@ -180,6 +182,63 @@ def test_worker_parks_on_drain_and_restarts_on_resubmit():
     fresh.submit(r3)
     fresh.run()
     assert r3.out_tokens == r2.out_tokens
+
+
+def test_concurrent_submits_start_one_worker(monkeypatch):
+    """Two client threads submit while the decode loop steps, and parks the
+    worker whenever the engine drains: at no time do two admission workers
+    run, and every request is served."""
+    from repro.serve import admission
+
+    cfg, model, params = _family_model("qwen2.5-3b")
+    eng = ServeEngine(model, params, EngineConfig(
+        batch_slots=2, max_len=32, async_prefill=True), RULES)
+    count_lock = threading.Lock()
+    running, peak = [0], [0]
+    worker = eng.pipeline._worker
+
+    def counted_worker():
+        with count_lock:
+            running[0] += 1
+            peak[0] = max(peak[0], running[0])
+        try:
+            worker()
+        finally:
+            with count_lock:
+                running[0] -= 1
+
+    eng.pipeline._worker = counted_worker
+
+    class SlowStart(threading.Thread):
+        # widens the gap between a kick's liveness check and the new
+        # worker's start, where a second kick could slip in
+        def start(self):
+            time.sleep(0.005)
+            super().start()
+
+    monkeypatch.setattr(admission.threading, "Thread", SlowStart)
+    reqs = _reqs(cfg, 12, max_new=3)
+    for r in range(3):
+        batch = reqs[4 * r: 4 * r + 4]
+        gate = threading.Barrier(2)
+
+        def client(mine, gate=gate):
+            gate.wait()
+            for req in mine:
+                eng.submit(req)
+
+        clients = [threading.Thread(target=client, args=(batch[i::2],))
+                   for i in range(2)]
+        for c in clients:
+            c.start()
+        while any(c.is_alive() for c in clients) or eng.load:
+            eng.step()
+        for c in clients:
+            c.join()
+        eng.step()                          # drained: parks the worker
+    eng.pipeline.shutdown()
+    assert peak[0] == 1
+    assert all(r.done and len(r.out_tokens) == 3 for r in reqs)
 
 
 def test_pipeline_error_surfaces_in_decode_loop():

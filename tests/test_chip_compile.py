@@ -8,6 +8,7 @@ convolutions at 224 px.  The topology is described only inside the fixture
 below, never at import: one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,9 @@ def test_paged_attention_compiles(one_chip):
         ((LANES,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+    # the benchmark's kernel reading finds the kernel's events by this name
+    assert re.search(r"%paged_attention(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', hlo)
 
 
 def test_flash_attention_compiles(one_chip):

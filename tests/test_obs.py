@@ -16,8 +16,12 @@ The load-bearing invariants:
   timestamps and histogram buckets are exact assertions, not tolerances.
 """
 import copy
+import glob
+import itertools
 import json
+import os
 import threading
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -85,6 +89,35 @@ def test_disabled_tracer_records_nothing():
     # serve-layer default call site
     assert NULL_TRACER.total == 0
     assert not NULL_TRACER.enabled
+
+
+def test_span_sites_with_both_sinks_off_allocate_nothing():
+    tr = NULL_TRACER
+    ev = tr.EV_DECODE_WAIT
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        for _ in itertools.repeat(None, 10_000):
+            tr.begin(ev)
+            tr.end(ev)
+        after = tracemalloc.get_traced_memory()[0]
+    finally:
+        tracemalloc.stop()
+    assert after == before
+    assert tr.total == 0 and not tr.enabled and not tr._annotate
+
+
+def test_profiler_sink_closes_spans_an_exception_left_open():
+    tr = ServeTracer(capacity=1, enabled=False, annotate=True)
+    tr.begin(tr.EV_STEP)
+    tr.begin(tr.EV_DECODE)
+    tr.begin(tr.EV_DECODE_WAIT)          # its end and decode.batch's skipped
+    tr.end(tr.EV_STEP)
+    assert tr._open.stack == []
+    tr.begin(tr.EV_LANES_FILL)
+    tr.end(tr.EV_LANES_FILL)
+    assert tr._open.stack == []
+    assert tr.total == 0                 # the ring stayed off
 
 
 def test_phase_vocabulary_matches_state_machine():
@@ -336,8 +369,10 @@ def test_traced_engine_lifecycles_and_token_identity(small_model, tmp_path):
     # a preempted request visits waiting again mid-flight
     assert any(ph.count("waiting") > 1 for ph in hist.values())
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"engine.step", "decode.batch", "prefill.chunk",
-            "admission.reserve", "pages.free"} <= names
+    assert {"engine.step", "lanes.fill", "pages.ensure", "decode.batch",
+            "decode.inputs", "decode.dispatch", "decode.wait",
+            "decode.fetch", "decode.sample", "prefill.chunk",
+            "prefill.sample", "admission.reserve", "pages.free"} <= names
     assert {"swap_out.batch", "swap_in.stage"} & names
     # both loops own a labelled thread track
     tracks = {e["args"]["name"] for e in trace["traceEvents"]
@@ -389,7 +424,119 @@ def test_trace_annotations_smoke(small_model):
     annot, toks_a = _run(model, params, cfg, n=3, batch_slots=2, max_len=32,
                          trace_annotations=True)
     plain, toks_p = _run(model, params, cfg, n=3, batch_slots=2, max_len=32)
-    assert toks_a == toks_p              # profiler spans change nothing
+    both, toks_b = _run(model, params, cfg, n=3, batch_slots=2, max_len=32,
+                        trace=True, trace_annotations=True)
+    assert toks_a == toks_p == toks_b    # profiler spans change nothing
+    # the profiler sink runs without the ring, on a tracer of its own
+    assert annot.tracer is not NULL_TRACER and annot.tracer._annotate
+    assert not annot.tracer.enabled and annot.tracer.total == 0
+    assert both.tracer.enabled and both.tracer.total > 0
+    assert not hasattr(annot, "_annot")
+
+
+def _profiled_names(trace_dir) -> set:
+    import jax
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return {e.name for plane in pd.planes for line in plane.lines
+            for e in line.events}
+
+
+def test_annotations_reach_the_profiler_without_the_ring(small_model,
+                                                         tmp_path):
+    import jax
+
+    cfg, model, params = small_model
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng, toks = _run(model, params, cfg, n=3, batch_slots=2, max_len=32,
+                         prefill_chunk=4, trace_annotations=True)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(toks) == 3
+    names = {n for n in _profiled_names(tmp_path) if n.startswith("repro.")}
+    assert {"repro.engine.step", "repro.lanes.fill", "repro.pages.ensure",
+            "repro.decode.batch", "repro.decode.inputs",
+            "repro.decode.dispatch", "repro.decode.wait",
+            "repro.decode.fetch", "repro.decode.sample",
+            "repro.prefill.chunk", "repro.prefill.sample"} <= names
+    assert "repro.decode_step" not in names
+    assert eng.tracer.total == 0         # the ring was off throughout
+    # the shared disabled tracer never saw either sink
+    assert NULL_TRACER.total == 0
+    assert not NULL_TRACER.enabled and not NULL_TRACER._annotate
+    assert getattr(NULL_TRACER._open, "stack", None) is None
+
+
+def test_decode_split_histograms_add_up_per_step(small_model):
+    """prep + wait + sample covers one decoding step from its start to its
+    last token, inside the step's latency; each is observed once per step
+    that decodes.  A clock that ticks 2**-10 s per read keeps every sum
+    exact."""
+    from repro.models.common import AxisRules, DEFAULT_RULES
+    from repro.serve import AdmissionConfig, EngineConfig, ServeEngine
+
+    cfg, model, params = small_model
+    ticks = itertools.count()
+    obs_clock.set_source(lambda: next(ticks) * 2.0 ** -10)
+    try:
+        eng = ServeEngine(model, params, EngineConfig(
+            batch_slots=2, max_len=32,
+            admission=AdmissionConfig(prefill_chunk=4)),
+            AxisRules(DEFAULT_RULES))
+        names = ("decode.prep_s", "decode.wait_s", "decode.sample_s")
+
+        def cut():
+            snap = eng.metrics.snapshot()
+            h = snap["histograms"]
+            return (snap["counters"]["decode_tokens"],
+                    [(h[n]["count"], h[n]["sum"]) for n in names],
+                    h["step_latency_s"]["sum"])
+
+        for r in _reqs(cfg, 4):
+            eng.submit(r)
+        decoding_steps = 0
+        prev = cut()
+        while eng.load:
+            eng.step()
+            now = cut()
+            decoded = now[0] > prev[0]
+            decoding_steps += decoded
+            counts = [c1 - c0 for (c1, _), (c0, _) in zip(now[1], prev[1])]
+            assert counts == [int(decoded)] * 3
+            split = sum(s1 - s0 for (_, s1), (_, s0) in zip(now[1], prev[1]))
+            assert 0 <= split <= now[2] - prev[2]
+            if decoded:
+                assert all(s1 > s0 for (_, s1), (_, s0) in zip(now[1], prev[1]))
+            prev = now
+        eng.pipeline.shutdown()
+    finally:
+        obs_clock.reset_source()
+    tel = eng.telemetry()
+    assert decoding_steps > 0
+    for n in names:
+        assert tel["histograms"][n]["count"] == decoding_steps
+
+
+def test_program_names_the_device_trace_matches(small_model):
+    """The benchmark finds the decode program by ``_decode_impl`` and the
+    whole-prompt prefill by ``_prefill_impl`` in the device trace."""
+    import jax.numpy as jnp
+
+    from repro.models.common import AxisRules, DEFAULT_RULES
+    from repro.serve import EngineConfig, ServeEngine
+
+    cfg, model, params = small_model
+    eng = ServeEngine(model, params, EngineConfig(batch_slots=2, max_len=32),
+                      AxisRules(DEFAULT_RULES))
+    decode = eng._decode.lower(eng.params, eng.cache.pools,
+                               *eng.decode_inputs()).as_text()
+    assert decode.split("\n", 1)[0].startswith("module @jit__decode_impl")
+    prefill = eng._prefill.lower(eng.params,
+                                 jnp.zeros((1, 8), jnp.int32)).as_text()
+    assert prefill.split("\n", 1)[0].startswith("module @jit__prefill_impl")
 
 
 # -- router integration ------------------------------------------------------
