@@ -230,6 +230,16 @@ def paged_gather(pool: jax.Array, block_table: jax.Array,
     return out[..., :f].reshape((b, p) + page_shape)
 
 
+def paged_block_pages(page_size: int, pages_per_lane: int) -> int:
+    """Pages ``paged_attention`` streams per loop step: the fewest that hold
+    at least 128 tokens (one lane-width score tile) and divide the block
+    table's width, else the whole width."""
+    for n in range(1, pages_per_lane + 1):
+        if pages_per_lane % n == 0 and n * page_size >= 128:
+            return n
+    return pages_per_lane
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(
     q: jax.Array,             # (B, H, D) one decode token per lane
@@ -240,7 +250,9 @@ def paged_attention(
     scale: float | None = None,
     interpret: bool | None = None,
 ):
-    """Fused paged decode-attention read (GQA grouped, online softmax)."""
+    """Fused paged decode-attention read (GQA grouped, online softmax):
+    each lane reads only the pages its length reaches, a block of
+    ``paged_block_pages`` pages at a time."""
     b, h, d = q.shape
     n, ps, hkv, _ = k_pool.shape
     rep = h // hkv
@@ -251,6 +263,7 @@ def paged_attention(
     vp = _pad_to(v_pool.transpose(2, 0, 1, 3), 3, dp)
     out = _pa.paged_decode_attention(
         qg, kp, vp, block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+        pages_per_block=paged_block_pages(ps, block_table.shape[1]),
         scale=float(scale) if scale is not None else float(d) ** -0.5,
         interpret=_interpret(interpret),
     )

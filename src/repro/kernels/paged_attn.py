@@ -12,7 +12,9 @@ Two kernels cover the read path:
 * ``paged_decode_attention`` — the fused read: one decode query per lane
   attends directly over the pages its block table names with a streaming
   online-softmax accumulator (the ``flash_attention`` dataflow), never
-  materializing the dense view.
+  materializing the dense view.  It reads only the pages the lane's length
+  reaches, a block of pages per loop step, DMA'd from HBM while the
+  previous block is attended.
 
 Both have pure-jnp oracles in ``ref.py``; ``ops.py`` holds the padded,
 interpret-off-TPU public wrappers.
@@ -27,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-_LANE = 128
 
 
 # ---------------------------------------------------------------------------
@@ -81,56 +82,86 @@ def _paged_attn_kernel(
     bt_ref,           # (B, P) int32 scalar-prefetch block table
     len_ref,          # (B,) int32 valid tokens per lane (current token incl.)
     q_ref,            # (1, 1, rep, D)
-    k_ref,            # (1, 1, PS, D) — the page this grid step streams
-    v_ref,            # (1, 1, PS, D)
+    k_hbm,            # (Hkv, n_pool, PS, D) — stays in HBM, pages DMA'd
+    v_hbm,            # (Hkv, n_pool, PS, D)
     o_ref,            # (1, 1, rep, D)
-    acc_ref,          # (rep, D) f32
-    m_ref,            # (rep, _LANE) f32 lane-replicated running max
-    l_ref,            # (rep, _LANE) f32 lane-replicated running sum
+    k_buf,            # (2, ppb, PS, D) double-buffered block of K pages
+    v_buf,            # (2, ppb, PS, D)
+    sems,             # DMA semaphores (2, 2): [K/V, buffer slot]
     *,
     n_pages: int,
-    page_size: int,
+    pages_per_block: int,
     scale: float,
 ):
-    b, pi = pl.program_id(0), pl.program_id(2)
+    b, g = pl.program_id(0), pl.program_id(1)
+    _, _, ps, d = k_buf.shape
+    bk = pages_per_block * ps
+    length = len_ref[b]
+    # only the blocks the lane's length reaches: a lane of length 0 walks
+    # none, and no block lies past the last one the length needs
+    n_blocks = jnp.minimum((length + bk - 1) // bk, n_pages // pages_per_block)
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copies(blk, slot):
+        out = []
+        for j in range(pages_per_block):
+            page = jnp.maximum(bt_ref[b, blk * pages_per_block + j], 0)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[g, page], k_buf.at[slot, j], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[g, page], v_buf.at[slot, j], sems.at[1, slot]))
+        return out
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (rep, D)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (PS, D)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                    # (rep, PS)
+    @pl.when(n_blocks > 0)
+    def _prefetch_first():
+        for c in copies(0, 0):
+            c.start()
 
-    rep, ps = s.shape
-    kpos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, (rep, ps), 1)
-    mask = (kpos < len_ref[b]) & (bt_ref[b, pi] >= 0)
-    s = jnp.where(mask, s, NEG_INF)
+    q = q_ref[0, 0]                                      # (rep, D)
+    rep = q.shape[0]
 
-    m_prev = m_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(mask, p, 0.0)                          # all-masked pages
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = jnp.broadcast_to(l_ref[:, :1] * alpha
-                                  + jnp.sum(p, axis=1, keepdims=True),
-                                  l_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_ref[0, 0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    def body(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = i % 2
 
-    @pl.when(pi == n_pages - 1)
-    def _flush():
-        l = l_ref[:, :1]
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30))[None, None].astype(
-            o_ref.dtype
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch_next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        k = k_buf[slot].reshape(bk, d)
+        v = v_buf[slot].reshape(bk, d).astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                        # (rep, bk)
+
+        kpos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (rep, bk), 1)
+        mask = kpos < length
+        for j in range(pages_per_block):
+            in_page = (kpos // ps) == i * pages_per_block + j
+            mask &= ~in_page | (bt_ref[b, i * pages_per_block + j] >= 0)
+        s = jnp.where(mask, s, NEG_INF)
+        # rows past the length may hold pages the table never filled (a
+        # freed page, anything): zero them so 0 x NaN cannot reach acc
+        vpos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, d), 0)
+        v = jnp.where(vpos < length, v, 0.0)
+
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(mask, p, 0.0)                      # all-masked pages
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
+        return m_new, l_new, acc
+
+    m0 = jnp.full((rep, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rep, 1), jnp.float32)
+    acc0 = jnp.zeros((rep, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30))[None, None].astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -139,35 +170,39 @@ def paged_decode_attention(
     v_pool: jax.Array,        # (Hkv, n_pages, PS, D)
     block_table: jax.Array,   # (B, pages_per_lane) int32, -1 = unallocated
     lengths: jax.Array,       # (B,) int32 — tokens valid in the pages
+    pages_per_block: int,     # pages one loop step streams; divides P
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
+    """One grid step per (lane, KV head); inside it a loop over the blocks
+    of ``pages_per_block`` pages the lane's length reaches, each block's
+    pages copied from HBM into one half of a VMEM double buffer while the
+    other half is attended."""
     b, hkv, rep, d = q.shape
-    _, n_pool, ps, _ = k_pool.shape
+    _, _, ps, _ = k_pool.shape
     _, p = block_table.shape
+    if p % pages_per_block:
+        raise ValueError(
+            f"pages_per_block {pages_per_block} does not divide {p} pages")
     scale = float(scale) if scale is not None else float(d) ** -0.5
     kern = functools.partial(
-        _paged_attn_kernel, n_pages=p, page_size=ps, scale=scale,
+        _paged_attn_kernel, n_pages=p, pages_per_block=pages_per_block,
+        scale=scale,
     )
+    q_spec = pl.BlockSpec((1, 1, rep, d), lambda b_, g, bt, ln: (b_, g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, p),
+        grid=(b, hkv),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, d),
-                         lambda b_, g, pi, bt, ln: (b_, g, 0, 0)),
-            pl.BlockSpec((1, 1, ps, d),
-                         lambda b_, g, pi, bt, ln:
-                         (g, jnp.maximum(bt[b_, pi], 0), 0, 0)),
-            pl.BlockSpec((1, 1, ps, d),
-                         lambda b_, g, pi, bt, ln:
-                         (g, jnp.maximum(bt[b_, pi], 0), 0, 0)),
+            q_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda b_, g, pi, bt, ln: (b_, g, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rep, d), jnp.float32),
-            pltpu.VMEM((rep, _LANE), jnp.float32),
-            pltpu.VMEM((rep, _LANE), jnp.float32),
+            pltpu.VMEM((2, pages_per_block, ps, d), k_pool.dtype),
+            pltpu.VMEM((2, pages_per_block, ps, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     return pl.pallas_call(

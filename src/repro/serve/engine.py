@@ -346,6 +346,17 @@ class ServeEngine:
         self._h_prep = m.histogram("decode.prep_s")
         self._h_wait = m.histogram("decode.wait_s")
         self._h_sample = m.histogram("decode.sample_s")
+        # the share of the Pallas paged kernel's KV blocks a step walks: the
+        # blocks each active lane's length reaches over every lane's blocks
+        # (observed only where the paged decode runs that kernel)
+        self._h_kv_blocks = m.histogram(
+            "decode.kv_block_share", tuple(i / 10 for i in range(1, 11)))
+        self._kv_block_tokens = None
+        if ecfg.decode_path == "paged" and ecfg.attn_impl == "pallas":
+            from repro.kernels.ops import paged_block_pages
+
+            self._kv_block_tokens = ps * paged_block_pages(
+                ps, self.cache.pages_per_lane)
         sanitizer.register_engine(self)
         self.pipeline = AdmissionPipeline(self, ecfg.async_prefill)
         self._idle_since: float | None = None
@@ -1057,12 +1068,25 @@ class ServeEngine:
         tr.end(tr.EV_DECODE_SAMPLE)
         tr.end(tr.EV_DECODE, n_active)
         t_done = obs_clock.monotonic()
+        kv_share = self._kv_block_share(positions, active)
         with self._lock:
             self._c_decode.inc(done)
             self._c_lane_step.inc(n_active)
             self._h_prep.observe(t_sent - t_step)
             self._h_wait.observe(t_ready - t_sent)
             self._h_sample.observe(t_done - t_ready)
+            if kv_share is not None:
+                self._h_kv_blocks.observe(kv_share)
+
+    def _kv_block_share(self, positions, active) -> float | None:
+        """Blocks the paged kernel walks for these decode inputs (each
+        active lane's ``ceil((position + 1) / block_tokens)``) over all
+        lanes' blocks; None where the step does not run the kernel."""
+        blk = self._kv_block_tokens
+        if blk is None:
+            return None
+        walked = int(np.sum(-(-(positions[active] + 1) // blk)))
+        return walked * blk / (len(positions) * self.cache.capacity)
 
     # -- step loop -------------------------------------------------------------
 

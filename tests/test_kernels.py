@@ -183,6 +183,89 @@ def test_paged_decode_attention_ragged(rng, table, lengths, dt):
     )
 
 
+# lanes spanning several blocks: 24 pages of 16 tokens walk as three blocks
+# of 8 pages (128 tokens); lengths at and around the block boundaries
+_BLOCK_PS, _BLOCK_P = 16, 24
+
+
+def _block_lanes(rng, lengths, h, g, d, dt, n_pool):
+    """Pools, query and a block table over the pool's first lanes x P pages
+    whose entries past the pages a lane's length needs are -1 (as the
+    serving cache leaves them)."""
+    b = len(lengths)
+    kpool = jnp.asarray(rng.normal(size=(n_pool, _BLOCK_PS, g, d)), dt)
+    vpool = jnp.asarray(rng.normal(size=(n_pool, _BLOCK_PS, g, d)), dt)
+    q = jnp.asarray(rng.normal(size=(b, h, d)), dt)
+    need = -(-np.asarray(lengths) // _BLOCK_PS)
+    pages = rng.permutation(b * _BLOCK_P).reshape(b, _BLOCK_P)
+    table = np.where(np.arange(_BLOCK_P)[None] < need[:, None], pages, -1)
+    return q, kpool, vpool, table.astype(np.int32)
+
+
+def _paged_oracle(q, kpool, vpool, table, lengths):
+    b, h, d = q.shape
+    g = kpool.shape[2]
+    return ref.paged_decode_attention(
+        q.reshape(b, g, h // g, d), kpool.transpose(2, 0, 1, 3),
+        vpool.transpose(2, 0, 1, 3), jnp.asarray(table, jnp.int32),
+        jnp.asarray(lengths, jnp.int32),
+    ).reshape(b, h, d)
+
+
+@pytest.mark.parametrize("lengths", [
+    pytest.param([0, 1, 127, 128, 129, _BLOCK_P * _BLOCK_PS],
+                 id="empty-one-block-edges-full"),
+    pytest.param([255, 256, 257, 383, 16], id="second-third-block-edges"),
+])
+@pytest.mark.parametrize("h,g,d", [
+    pytest.param(16, 2, 128, id="qwen-h16-hkv2-d128"),
+    pytest.param(4, 2, 32, id="h4-hkv2-d32"),
+])
+@pytest.mark.parametrize("dt", [F32, BF16])
+def test_paged_decode_attention_multi_block(rng, lengths, h, g, d, dt):
+    """Lanes spanning several blocks of pages == the oracle: every valid
+    token attended, lanes of length 0 output zeros."""
+    assert ops.paged_block_pages(_BLOCK_PS, _BLOCK_P) == 8
+    q, kpool, vpool, table = _block_lanes(rng, lengths, h, g, d, dt,
+                                          n_pool=len(lengths) * _BLOCK_P)
+    got = ops.paged_attention(q, kpool, vpool, jnp.asarray(table),
+                              jnp.asarray(lengths, jnp.int32))
+    want = _paged_oracle(q, kpool, vpool, table, lengths)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **_tol(dt))
+
+
+@pytest.mark.parametrize("dt", [F32, BF16])
+def test_paged_decode_attention_never_reads_past_the_length(rng, dt):
+    """Table entries past the pages a lane's length needs point at a pool
+    page of NaN; none of it reaches the output, which is finite and equal
+    to the oracle over the valid prefix (where those entries are -1)."""
+    lengths = [0, 1, 20, 127, 129, 300]
+    h, g, d = 16, 2, 128
+    b = len(lengths)
+    q, kpool, vpool, table = _block_lanes(rng, lengths, h, g, d, dt,
+                                          n_pool=b * _BLOCK_P + 1)
+    nan_page = b * _BLOCK_P
+    kpool = kpool.at[nan_page].set(jnp.nan)
+    vpool = vpool.at[nan_page].set(jnp.nan)
+    poisoned = np.where(table >= 0, table, nan_page).astype(np.int32)
+    got = np.asarray(ops.paged_attention(
+        q, kpool, vpool, jnp.asarray(poisoned),
+        jnp.asarray(lengths, jnp.int32)), np.float32)
+    want = _paged_oracle(q, kpool, vpool, table, lengths)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), **_tol(dt))
+
+
+@pytest.mark.parametrize("page_size,pages,want", [
+    (16, 128, 8), (16, 24, 8), (16, 4, 4), (8, 128, 16), (64, 6, 2),
+    (16, 7, 7), (128, 3, 1),
+])
+def test_paged_block_pages(page_size, pages, want):
+    """The fewest pages that hold 128 tokens and divide the table, else all."""
+    assert ops.paged_block_pages(page_size, pages) == want
+
+
 def test_paged_decode_attention_matches_model_xla_path(rng):
     """The fused kernel and the model layer's XLA paged path agree on the
     same pools/table/positions (positions = lengths - 1)."""

@@ -520,6 +520,54 @@ def test_decode_split_histograms_add_up_per_step(small_model):
         assert tel["histograms"][n]["count"] == decoding_steps
 
 
+def test_kv_block_share_counts_the_blocks_the_kernel_walks(small_model):
+    """``decode.kv_block_share`` is observed once per decoding step of the
+    Pallas paged path: the blocks each active lane's length reaches, over
+    every lane's blocks, for the lengths the step's decode inputs hold.  Pages
+    of 4 tokens and a 512-token lane give 128 pages, walked as 4 blocks of
+    32 pages (128 tokens)."""
+    from repro.models.common import AxisRules, DEFAULT_RULES
+    from repro.serve import (AdmissionConfig, CacheConfig, EngineConfig,
+                             Request, ServeEngine)
+
+    cfg, model, params = small_model
+    eng = ServeEngine(model, params, EngineConfig(
+        batch_slots=3, max_len=512,
+        cache=CacheConfig(page_size=4, attn_impl="pallas"),
+        admission=AdmissionConfig(async_prefill=False)),
+        AxisRules(DEFAULT_RULES))
+    seen = []
+    real_inputs = eng.decode_inputs
+
+    def recorded():
+        out = real_inputs()
+        seen.append((out[2].copy(), out[3].copy()))
+        return out
+
+    eng.decode_inputs = recorded
+    rng = np.random.default_rng(3)
+    for i, plen in enumerate((126, 127)):       # lengths 127/128 first
+        eng.submit(Request(uid=i, max_new_tokens=3,
+                           prompt=rng.integers(0, cfg.vocab_size, size=plen,
+                                               dtype=np.int32)))
+    shares, prev = [], (0, 0.0)
+    while eng.load:
+        eng.step()
+        h = eng.metrics.snapshot()["histograms"]["decode.kv_block_share"]
+        if h["count"] > prev[0]:
+            assert h["count"] == prev[0] + 1
+            shares.append(h["sum"] - prev[1])
+        prev = (h["count"], h["sum"])
+    eng.pipeline.shutdown()
+    # the two steps by hand: lengths 127 and 128 walk one block each, then
+    # 128 and 129 walk one and two, of 3 lanes x 4 blocks
+    assert [sorted(int(p) + 1 for p in pos[act]) for pos, act in seen] == [
+        [127, 128], [128, 129]]
+    np.testing.assert_allclose(shares, [2 / 12, 3 / 12], rtol=0, atol=1e-12)
+    tel = eng.telemetry()
+    assert tel["histograms"]["decode.kv_block_share"]["count"] == len(seen)
+
+
 def test_program_names_the_device_trace_matches(small_model):
     """The benchmark finds the decode program by ``_decode_impl`` and the
     whole-prompt prefill by ``_prefill_impl`` in the device trace."""
