@@ -134,22 +134,24 @@ def bench_json() -> dict:
     out["attn.paged_decode.oracle_ratio"] = impl_us / oracle_us
     out["attn.paged_decode.tuned_ratio"] = impl_us / default_us
 
-    # SSD chunk scan (the production XLA dual form with the factorized
+    # SSD chunk scan (the production XLA dual form with the exact masked
     # decay — models/ssm.ssd_chunked) vs the exact sequential recurrence
     # oracle (ref.ssd_scan); Mamba-2 decode/prefill hot path
     from repro.models.ssm import ssd_chunked
 
-    cfg, xh, bbn, ccn, dtn, a_log, d_skip = cutouts.build("ssd.chunked")
+    cfg, xh, bbn, ccn, dtn, a_log, d_skip, dt_bias = cutouts.build(
+        "ssd.chunked")
     impl = jax.jit(lambda *a: ssd_chunked(cfg, *a)[0])
     oracle = jax.jit(
         lambda xx, bb_, cc_, dd: ref.ssd_scan(
-            xx, bb_, cc_, jax.nn.softplus(dd), -jnp.exp(a_log)
+            xx, bb_, cc_, jax.nn.softplus(dd + dt_bias), -jnp.exp(a_log)
         )
     )
-    impl_us = _med_time(impl, xh, bbn, ccn, dtn, a_log, d_skip)
+    impl_us = _med_time(impl, xh, bbn, ccn, dtn, a_log, d_skip, dt_bias)
     with no_tuning():
         dflt = jax.jit(lambda *a: ssd_chunked(cfg, *a)[0])
-        default_us = _med_time(dflt, xh, bbn, ccn, dtn, a_log, d_skip)
+        default_us = _med_time(dflt, xh, bbn, ccn, dtn, a_log, d_skip,
+                               dt_bias)
     oracle_us = _med_time(oracle, xh, bbn, ccn, dtn)
     out["ssd.chunked.us"] = round(impl_us, 1)
     out["ssd.chunked.oracle_ratio"] = impl_us / oracle_us

@@ -69,7 +69,7 @@ WHISPER_LARGE_V3 = register(ArchConfig(
     name="whisper-large-v3", family="audio",
     n_layers=32, d_model=1280, n_heads=20, n_kv_heads=20, d_ff=5120,
     vocab_size=51866, head_dim=64,
-    act="gelu", learned_positions=True, norm_eps=1e-5, train_microbatches=4,
+    act="gelu", rope=False, norm_eps=1e-5, train_microbatches=4,
     max_position=32768,
     encoder=EncoderConfig(n_layers=32, n_ctx=1500),
     source="arXiv:2212.04356 (enc-dec; conv frontend stub provides frames)",
@@ -109,9 +109,22 @@ RECURRENTGEMMA_9B = register(ArchConfig(
     n_layers=38, d_model=4096, n_heads=16, n_kv_heads=1, d_ff=12288,
     vocab_size=256000, head_dim=256,
     act="gelu", rms_plus_one=True, embed_scale=True, train_microbatches=4,
-    rglru=RGLRUConfig(lru_width=4096, d_conv=4,
-                      block_pattern=("rec", "rec", "attn"), attn_window=2048),
+    layer_pattern=("rec", "rec", "attn"),
+    rglru=RGLRUConfig(lru_width=4096, d_conv=4, attn_window=2048),
     source="arXiv:2402.19427 (Griffin: RG-LRU + local attn 1:2, MQA kv=1)",
+))
+
+GRANITE_4_H_MICRO = register(ArchConfig(
+    name="granite-4.0-h-micro", family="hybrid",
+    n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8, d_ff=8192,
+    vocab_size=100352, head_dim=64, tie_embeddings=True, norm_eps=1e-5,
+    act="silu", rope=False, attention_multiplier=0.015625,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+    # attention at layers 5, 15, 25, 35: four periods of ten
+    layer_pattern=("ssm",) * 5 + ("dense",) + ("ssm",) * 4,
+    ssm=SSMConfig(d_state=128, d_conv=4, expand=2, head_dim=64, chunk=256),
+    source="hf:ibm-granite/granite-4.0-h-micro (Mamba-2 + NoPE GQA 32/8, "
+           "shared SwiGLU MLP in every layer, scalar multipliers)",
 ))
 
 # --- SSM --------------------------------------------------------------------

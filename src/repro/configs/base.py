@@ -32,11 +32,6 @@ class SSMConfig:
     expand: int = 2
     head_dim: int = 64
     chunk: int = 256
-    # §Perf: factorize the intra-chunk decay exp(seg_i - seg_j) into
-    # exp(seg_i - c)·exp(c - seg_j) — removes the (Q,Q,H) decay tensors
-    # entirely (the causal mask is (Q,Q), H-free).  c = chunk midpoint for
-    # numerical stability (exponents bounded by half the chunk decay range).
-    factorized: bool = True
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -51,7 +46,6 @@ class RGLRUConfig:
 
     lru_width: int = 4096
     d_conv: int = 4
-    block_pattern: tuple[str, ...] = ("rec", "rec", "attn")   # 1:2 ratio
     attn_window: int = 2048
 
 
@@ -87,14 +81,26 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     sliding_window: int | None = None
-    learned_positions: bool = False   # whisper decoder
+    rope: bool = True                 # off: whisper's learned positions, NoPE
+    attention_multiplier: float | None = None   # softmax scale; None = hd**-0.5
     max_position: int = 1 << 20
     # embedding / head
     tie_embeddings: bool = False
-    embed_scale: bool = False         # gemma: x *= sqrt(d_model)
+    # gemma: x *= sqrt(d_model); a flag, not a multiplier, because the
+    # factor follows d_model, which reduced() changes
+    embed_scale: bool = False
     rms_plus_one: bool = False        # gemma: (1 + w) RMSNorm weight
     act: str = "silu"
     norm_eps: float = 1e-6
+    # scalar multipliers (Granite): embeddings x embedding_multiplier, every
+    # residual branch x residual_multiplier, logits / logits_scaling; 1.0
+    # leaves the graph untouched
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # hybrids: the layer kinds of one period, repeated (scanned) over depth
+    # with a remainder segment; () = the family's uniform stack
+    layer_pattern: tuple[str, ...] = ()
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
@@ -168,7 +174,7 @@ class ArchConfig:
         """Tiny same-family config for CPU smoke tests."""
         kw: dict = dict(
             name=self.name + "-smoke",
-            n_layers=min(self.n_layers, 2 if self.family != "hybrid" else 3),
+            n_layers=min(self.n_layers, len(self.layer_pattern) or 2),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,
@@ -191,7 +197,6 @@ class ArchConfig:
                                     head_dim=32, chunk=32))
         if self.rglru:
             kw.update(rglru=RGLRUConfig(lru_width=128, d_conv=4,
-                                        block_pattern=("rec", "rec", "attn"),
                                         attn_window=64))
         if self.encoder:
             kw.update(encoder=EncoderConfig(n_layers=2, n_ctx=64))

@@ -43,11 +43,9 @@ VARIANTS = {
     # sequence-sharded activations (SP) for train
     "seq_parallel": ({"seq": "model", "cache_seq": "model"}, {}),
     # SSD ablations
-    "ssd_unfactorized": ({}, {"__ssd_factorized": False}),
     "ssd_chunk128": ({}, {"__ssd_chunk": 128}),
     "ssd_chunk128_mb4": ({}, {"__ssd_chunk": 128, "train_microbatches": 4}),
     "ssd_chunk256": ({}, {"__ssd_chunk": 256}),
-    "ssd_chunk256_unfact": ({}, {"__ssd_chunk": 256, "__ssd_factorized": False}),
     "ssd_chunk512": ({}, {"__ssd_chunk": 512}),
     "ssd_chunk256_mb2": ({}, {"__ssd_chunk": 256, "train_microbatches": 2}),
     "mb8": ({}, {"train_microbatches": 8}),
@@ -70,12 +68,7 @@ def _resolve_overrides(arch: str, v: str):
         from repro.configs import get_arch
 
         base = get_arch(arch).ssm
-        kw = {}
-        if "__ssd_factorized" in co:
-            kw["factorized"] = co.pop("__ssd_factorized")
-        if "__ssd_chunk" in co:
-            kw["chunk"] = co.pop("__ssd_chunk")
-        co["ssm"] = dataclasses.replace(base, **kw)
+        co["ssm"] = dataclasses.replace(base, chunk=co.pop("__ssd_chunk"))
     return ro, co
 
 

@@ -8,11 +8,13 @@ and dry-run cost analysis both depend on this):
     dense   : [("dense",) × L]
     moe     : [("dense",) × first_dense] + [("moe",) × (L-first_dense)]
     ssm     : [("ssm",) × L]
-    hybrid  : [("rec","rec","attn") × (L//3)] + remainder
+    hybrid  : [cfg.layer_pattern × (L // period)] + remainder, e.g.
+              ("rec","rec","attn") or ("ssm",)×5 + ("dense",) + ("ssm",)×4
     vlm     : dense backbone + embedding injection (api.py)
 
 Each layer = pre-norm temporal mix (attention / MLA / SSD / RG-LRU)
-+ residual [+ pre-norm MLP/MoE + residual].
++ residual [+ pre-norm MLP/MoE + residual]; each branch is scaled by
+``cfg.residual_multiplier`` where that is not 1.
 """
 from __future__ import annotations
 
@@ -82,15 +84,15 @@ def _qkv(cfg, p, x):
 def attn_apply(cfg, p, x, rules, positions, window=None, impl="xla"):
     b, s, d = x.shape
     q, k, v = _qkv(cfg, p, x)
-    if not cfg.learned_positions:
+    if cfg.rope:
         q = rope(q, positions[None], cfg.rope_theta)
         k = rope(k, positions[None], cfg.rope_theta)
     q = constrain(q, rules, "batch", "seq", "act_heads", None)
     k = constrain(k, rules, "batch", "seq", "kv_heads", None)
     v = constrain(v, rules, "batch", "seq", "kv_heads", None)
     out = attend(
-        q, k, v, causal=True, window=window, q_positions=positions,
-        impl=impl, chunk=cfg.attn_chunk,
+        q, k, v, causal=True, window=window, scale=cfg.attention_multiplier,
+        q_positions=positions, impl=impl, chunk=cfg.attn_chunk,
     )
     out = constrain(out, rules, "batch", "seq", "act_heads", None)
     y = out.reshape(b, s, cfg.n_heads * cfg.hd) @ p["wo"]
@@ -106,7 +108,7 @@ def attn_decode(cfg, p, x, cache, position, rules, window=None):
     rope_pos = position[:, None] if per_slot else jnp.full((1, 1), position,
                                                           jnp.int32)
     q, k, v = _qkv(cfg, p, x)
-    if not cfg.learned_positions:
+    if cfg.rope:
         q = rope(q, rope_pos, cfg.rope_theta)
         k = rope(k, rope_pos, cfg.rope_theta)
     if per_slot:
@@ -122,7 +124,8 @@ def attn_decode(cfg, p, x, cache, position, rules, window=None):
         )
     kc = constrain(kc, rules, "batch", "cache_seq", "kv_heads", None)
     vc = constrain(vc, rules, "batch", "cache_seq", "kv_heads", None)
-    out = decode_attention(q, kc, vc, position=position, window=window)
+    out = decode_attention(q, kc, vc, position=position, window=window,
+                           scale=cfg.attention_multiplier)
     y = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["wo"]
     return y, {"k": kc, "v": vc}
 
@@ -142,7 +145,7 @@ def attn_decode_paged(cfg, p, x, cache, block_table, positions, active, rules,
     n_pages, ps = cache["k"].shape[0], cache["k"].shape[1]
     positions = jnp.asarray(positions, jnp.int32)
     q, k, v = _qkv(cfg, p, x)
-    if not cfg.learned_positions:
+    if cfg.rope:
         q = rope(q, positions[:, None], cfg.rope_theta)
         k = rope(k, positions[:, None], cfg.rope_theta)
     page = jnp.take_along_axis(
@@ -163,11 +166,13 @@ def attn_decode_paged(cfg, p, x, cache, block_table, positions, active, rules,
 
         lengths = jnp.where(active, positions + 1, 0)
         out = kops.paged_attention(
-            q.reshape(b, cfg.n_heads, cfg.hd), kc, vc, block_table, lengths
+            q.reshape(b, cfg.n_heads, cfg.hd), kc, vc, block_table, lengths,
+            scale=cfg.attention_multiplier,
         )[:, None]
     else:
         out = paged_decode_attention_xla(
-            q, kc, vc, block_table, positions, window=window
+            q, kc, vc, block_table, positions, window=window,
+            scale=cfg.attention_multiplier,
         )
     y = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["wo"]
     return y, {"k": kc, "v": vc}
@@ -180,7 +185,7 @@ def attn_extend(cfg, p, x, cache, position, rules, window=None, impl="xla"):
     b, c, d = x.shape
     positions = position + jnp.arange(c, dtype=jnp.int32)
     q, k, v = _qkv(cfg, p, x)
-    if not cfg.learned_positions:
+    if cfg.rope:
         q = rope(q, positions[None], cfg.rope_theta)
         k = rope(k, positions[None], cfg.rope_theta)
     kc = jax.lax.dynamic_update_slice_in_dim(
@@ -190,8 +195,9 @@ def attn_extend(cfg, p, x, cache, position, rules, window=None, impl="xla"):
         cache["v"], v.astype(cache["v"].dtype), position, axis=1
     )
     out = attend(
-        q, kc, vc, causal=True, window=window, q_positions=positions,
-        kv_len=position + c, impl=impl, chunk=cfg.attn_chunk,
+        q, kc, vc, causal=True, window=window, scale=cfg.attention_multiplier,
+        q_positions=positions, kv_len=position + c, impl=impl,
+        chunk=cfg.attn_chunk,
     )
     y = out.reshape(b, c, cfg.n_heads * cfg.hd) @ p["wo"]
     return y, {"k": kc, "v": vc}
@@ -253,6 +259,9 @@ def layer_specs(cfg, kind: str) -> dict:
         s["moe"] = _moe.moe_specs(cfg)
     elif kind == "ssm":
         s["mix"] = _ssm.ssm_specs(cfg)
+        if cfg.d_ff:              # hybrids carry an MLP after the mixer
+            s["ln2"] = PSpec((cfg.d_model,), ("embed",), dt32, ln_init)
+            s["mlp"] = mlp_specs(cfg)
     elif kind == "rec":
         s["mix"] = _rglru.rglru_specs(cfg)
         s["ln2"] = PSpec((cfg.d_model,), ("embed",), dt32, ln_init)
@@ -270,82 +279,77 @@ def _norm(cfg, w, x):
     return rms_norm(x, w, cfg.norm_eps, plus_one=cfg.rms_plus_one)
 
 
+def _residual(cfg, x, y):
+    """x plus a branch's output, scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
+def _ffn(cfg, kind, p, x, rules):
+    """The layer's second half at inference: pre-norm MLP (or MoE, no
+    dropped tokens) and its residual; an ``ssm`` layer without an MLP
+    passes x through."""
+    if "ln2" not in p:
+        return x
+    h = _norm(cfg, p["ln2"], x)
+    if kind == "moe":
+        y, _ = _moe.moe_ffn(cfg, p["moe"], h, rules, n_groups=1, drop=False)
+    else:
+        y = mlp_apply(cfg, p["mlp"], h, rules)
+    return _residual(cfg, x, y)
+
+
 def layer_apply(cfg, kind, p, x, rules, positions, impl="xla"):
     """Full-sequence forward.  Returns (x, cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     window = cfg.sliding_window
+    h = _norm(cfg, p["ln1"], x)
     if kind in ("dense", "moe"):
-        h = _norm(cfg, p["ln1"], x)
         if cfg.mla:
             y, cache = _mla.mla_attention(cfg, p["attn"], h, rules, positions, impl)
         else:
             y, cache = attn_apply(cfg, p["attn"], h, rules, positions, window, impl)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, aux = _moe.moe_ffn(cfg, p["moe"], h, rules)
-        else:
-            y = mlp_apply(cfg, p["mlp"], h, rules)
-        x = x + y
+        x = _residual(cfg, x, y)
+        if kind == "moe":          # training routing: capacity, aux loss
+            y, aux = _moe.moe_ffn(cfg, p["moe"], _norm(cfg, p["ln2"], x), rules)
+            return _residual(cfg, x, y), cache, aux
     elif kind == "ssm":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _ssm.ssm_block(cfg, p["mix"], h, rules)
-        x = x + y
+        x = _residual(cfg, x, y)
     elif kind == "rec":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _rglru.rglru_block(cfg, p["mix"], h, rules)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
+        x = _residual(cfg, x, y)
     elif kind == "attn":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = attn_apply(
             cfg, p["attn"], h, rules, positions, cfg.rglru.attn_window, impl
         )
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
+        x = _residual(cfg, x, y)
     else:
         raise ValueError(kind)
-    return x, cache, aux
+    return _ffn(cfg, kind, p, x, rules), cache, aux
 
 
 def layer_decode(cfg, kind, p, x, cache, position, rules):
     window = cfg.sliding_window
+    h = _norm(cfg, p["ln1"], x)
     if kind in ("dense", "moe"):
-        h = _norm(cfg, p["ln1"], x)
         if cfg.mla:
             y, cache = _mla.mla_decode(cfg, p["attn"], h, cache, position, rules)
         else:
             y, cache = attn_decode(cfg, p["attn"], h, cache, position, rules, window)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, _ = _moe.moe_ffn(cfg, p["moe"], h, rules, n_groups=1, drop=False)
-        else:
-            y = mlp_apply(cfg, p["mlp"], h, rules)
-        x = x + y
     elif kind == "ssm":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _ssm.ssm_decode(cfg, p["mix"], h, cache, rules)
-        x = x + y
     elif kind == "rec":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _rglru.rglru_decode(cfg, p["mix"], h, cache, rules)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
     elif kind == "attn":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = attn_decode(
             cfg, p["attn"], h, cache, position, rules, cfg.rglru.attn_window
         )
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
     else:
         raise ValueError(kind)
-    return x, cache
+    x = _residual(cfg, x, y)
+    return _ffn(cfg, kind, p, x, rules), cache
 
 
 def layer_decode_paged(cfg, kind, p, x, cache, block_table, positions, active,
@@ -354,33 +358,21 @@ def layer_decode_paged(cfg, kind, p, x, cache, block_table, positions, active,
     kinds read/write the page pools through the block table; recurrent
     kinds step their per-lane state as in ``layer_decode``, with inactive
     lanes keeping their previous state (``absorb_decode`` semantics)."""
-    if kind in ("dense", "moe"):
+    if kind in ("dense", "moe", "attn"):
         h = _norm(cfg, p["ln1"], x)
         if cfg.mla:
             y, cache = _mla.mla_decode_paged(
                 cfg, p["attn"], h, cache, block_table, positions, active, rules
             )
         else:
+            window = (cfg.rglru.attn_window if kind == "attn"
+                      else cfg.sliding_window)
             y, cache = attn_decode_paged(
                 cfg, p["attn"], h, cache, block_table, positions, active,
-                rules, cfg.sliding_window, impl
+                rules, window, impl
             )
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, _ = _moe.moe_ffn(cfg, p["moe"], h, rules, n_groups=1, drop=False)
-        else:
-            y = mlp_apply(cfg, p["mlp"], h, rules)
-        x = x + y
-    elif kind == "attn":
-        h = _norm(cfg, p["ln1"], x)
-        y, cache = attn_decode_paged(
-            cfg, p["attn"], h, cache, block_table, positions, active, rules,
-            cfg.rglru.attn_window, impl
-        )
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
+        x = _residual(cfg, x, y)
+        x = _ffn(cfg, kind, p, x, rules)
     elif kind in ("ssm", "rec"):
         x, new_cache = layer_decode(cfg, kind, p, x, cache, positions, rules)
 
@@ -399,41 +391,25 @@ def layer_extend(cfg, kind, p, x, cache, position, rules):
     caches extend by a KV chunk, MLA by an absorbed latent chunk, and the
     recurrent kinds (ssm/rec) thread their stepped state through the chunk
     (so ``prefill_chunk`` applies to every family)."""
+    h = _norm(cfg, p["ln1"], x)
     if kind in ("dense", "moe"):
-        h = _norm(cfg, p["ln1"], x)
         if cfg.mla:
             y, cache = _mla.mla_extend(cfg, p["attn"], h, cache, position,
                                        rules)
         else:
             y, cache = attn_extend(cfg, p["attn"], h, cache, position, rules,
                                    cfg.sliding_window)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            y, _ = _moe.moe_ffn(cfg, p["moe"], h, rules, n_groups=1, drop=False)
-        else:
-            y = mlp_apply(cfg, p["mlp"], h, rules)
-        x = x + y
     elif kind == "attn":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = attn_extend(cfg, p["attn"], h, cache, position, rules,
                                cfg.rglru.attn_window)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
     elif kind == "ssm":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _ssm.ssm_extend(cfg, p["mix"], h, cache, rules)
-        x = x + y
     elif kind == "rec":
-        h = _norm(cfg, p["ln1"], x)
         y, cache = _rglru.rglru_extend(cfg, p["mix"], h, cache, rules)
-        x = x + y
-        h = _norm(cfg, p["ln2"], x)
-        x = x + mlp_apply(cfg, p["mlp"], h, rules)
     else:
         raise ValueError(kind)
-    return x, cache
+    x = _residual(cfg, x, y)
+    return _ffn(cfg, kind, p, x, rules), cache
 
 
 def layer_cache_spec(cfg, kind, batch, max_len):
@@ -452,12 +428,23 @@ def layer_cache_spec(cfg, kind, batch, max_len):
     raise ValueError(kind)
 
 
+# layer kinds whose cache is fixed-size per-lane state (no sequence dim)
+_STATE_KINDS = ("ssm", "rec")
+
+
 # ---------------------------------------------------------------------------
 # Segments (pattern × repeats, scanned)
 # ---------------------------------------------------------------------------
 
 
 def segments_for(cfg) -> list[tuple[tuple[str, ...], int]]:
+    if cfg.layer_pattern:
+        pat = tuple(cfg.layer_pattern)
+        n_full, rem = divmod(cfg.n_layers, len(pat))
+        segs = [(pat, n_full)]
+        if rem:
+            segs.append((pat[:rem], 1))
+        return segs
     if cfg.family in ("dense", "vlm"):
         return [(("dense",), cfg.n_layers)]
     if cfg.family == "moe":
@@ -468,14 +455,6 @@ def segments_for(cfg) -> list[tuple[tuple[str, ...], int]]:
         return segs
     if cfg.family == "ssm":
         return [(("ssm",), cfg.n_layers)]
-    if cfg.family == "hybrid":
-        pat = cfg.rglru.block_pattern
-        n_full = cfg.n_layers // len(pat)
-        rem = cfg.n_layers - n_full * len(pat)
-        segs = [(tuple(pat), n_full)]
-        if rem:
-            segs.append((tuple(pat[:rem]), 1))
-        return segs
     raise ValueError(cfg.family)
 
 
@@ -552,8 +531,10 @@ class DecoderLM:
     def _embed(self, params, tokens, rules):
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens, axis=0)
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        mult = cfg.embedding_multiplier * (
+            cfg.d_model ** 0.5 if cfg.embed_scale else 1.0)
+        if mult != 1.0:
+            x = x * jnp.asarray(mult, x.dtype)
         return constrain(x, rules, "batch", "seq", "act_embed")
 
     def _head(self, params, x, rules):
@@ -561,6 +542,8 @@ class DecoderLM:
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.rms_plus_one)
         w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = x @ w.astype(x.dtype)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         return constrain(logits, rules, "batch", "seq", "vocab")
 
     # -- forward (train) ----------------------------------------------------
@@ -737,7 +720,8 @@ class DecoderLM:
         inside each layer (``attn_decode_paged`` / ``mla_decode_paged``), so
         the engine never gathers a dense (B, max_len, ...) cache tree.
         Recurrent-state leaves keep the per-lane layout and step in place
-        (inactive lanes keep their state).  Stacked decode layout only."""
+        in the scan's carry (inactive lanes keep their state).  Stacked
+        decode layout only."""
         cfg = self.cfg
         if cfg.decode_unroll_layers:
             raise NotImplementedError("paged decode needs the stacked layout")
@@ -759,10 +743,36 @@ class DecoderLM:
                     new_cs[key] = c
                 return h, new_cs
 
+            carried = [f"s{i}_{k}" for i, k in enumerate(pattern)
+                       if k in _STATE_KINDS]
             if cfg.scan_layers and reps > 1:
-                x, new_cache = jax.lax.scan(
-                    body, x, (params[f"seg{si}"], pools[si])
+                # per-lane recurrent state rides the scan carry, so each
+                # layer's row is updated in place (a while loop's carry
+                # aliases its buffer); as xs -> ys the whole state would be
+                # written to a second buffer.  Page pools stay xs -> ys.
+                def carry_body(carry, xs, _body=body, _keys=carried):
+                    h, st = carry
+                    pslice, cs, r = xs
+                    cs = dict(cs)
+                    for k in _keys:
+                        cs[k] = jax.tree.map(
+                            lambda a: jax.lax.dynamic_index_in_dim(
+                                a, r, 0, keepdims=False), st[k])
+                    h, new_cs = _body(h, (pslice, cs))
+                    st = {k: jax.tree.map(
+                        lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                            a, u.astype(a.dtype), r, 0), st[k], new_cs.pop(k))
+                        for k in _keys}
+                    return (h, st), new_cs
+
+                seg_pools = pools[si]
+                (x, st), new_cache = jax.lax.scan(
+                    carry_body, (x, {k: seg_pools[k] for k in carried}),
+                    (params[f"seg{si}"],
+                     {k: v for k, v in seg_pools.items() if k not in carried},
+                     jnp.arange(reps)),
                 )
+                new_cache = {**new_cache, **st}
             else:
                 slices = []
                 for r in range(reps):

@@ -272,6 +272,8 @@ class ServeTracer(Tracer):
         # the decode batch — its inputs' upload, the program's enqueue, the
         # wait for its logits, their copy to the host, the per-lane choice
         self.EV_LANES_FILL = self.register("lanes.fill", ())
+        # inside lanes.fill: one lane's recurrent state written in place
+        self.EV_LANES_STATE = self.register("lanes.state", ("lane",))
         self.EV_PAGES_ENSURE = self.register("pages.ensure", ())
         self.EV_DECODE = self.register("decode.batch", ("lanes",))
         self.EV_DECODE_INPUTS = self.register("decode.inputs", ())

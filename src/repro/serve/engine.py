@@ -346,6 +346,10 @@ class ServeEngine:
         self._h_prep = m.histogram("decode.prep_s")
         self._h_wait = m.histogram("decode.wait_s")
         self._h_sample = m.histogram("decode.sample_s")
+        # the decode loop's time writing a lane's recurrent state at its
+        # assignment (families with per-lane state only)
+        self._h_state = m.histogram("lanes.state_write_s")
+        self._has_state = self.cache.has_state_leaves()
         # the share of the Pallas paged kernel's KV blocks a step walks: the
         # blocks each active lane's length reaches over every lane's blocks
         # (observed only where the paged decode runs that kernel)
@@ -865,11 +869,13 @@ class ServeEngine:
                     inserts.append(
                         (st, self.cache.snapshot_state(st.prefill_cache))
                     )
+                if self._has_state:
+                    self._write_lane_state(st.lane, st.prefill_cache)
                 self.cache.write_prefill(st.pages, st.prefill_cache,
-                                         lane=st.lane, skip_pages=skip)
+                                         skip_pages=skip)
                 st.prefill_cache = None
             if st.state_cache is not None:            # restored lane state
-                self.cache.write_state(st.lane, st.state_cache)
+                self._write_lane_state(st.lane, st.state_cache)
                 st.state_cache = None
         if self.cache.prefix is not None:
             post = [st for st in take if st.prefix_claim is not None]
@@ -883,6 +889,20 @@ class ServeEngine:
                         self.cache.prefix_insert(st.resume_tokens, st.pages,
                                                  state_np, st.pending_token)
         return bool(take)
+
+    @decode_loop_only
+    def _write_lane_state(self, lane: int, tree) -> None:
+        """Write a request's recurrent state into ``lane``'s rows in place.
+        The write is only enqueued here: the device runs it in order with
+        the prefill chunks queued ahead of it, and the next decode step
+        waits for it as for those."""
+        tr = self.tracer
+        t0 = obs_clock.monotonic()
+        tr.begin(tr.EV_LANES_STATE, lane)
+        self.cache.write_state(lane, tree)
+        tr.end(tr.EV_LANES_STATE, lane)
+        with self._lock:
+            self._h_state.observe(obs_clock.monotonic() - t0)
 
     # -- decode ----------------------------------------------------------------
 

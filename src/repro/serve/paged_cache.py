@@ -20,6 +20,7 @@ is selectable via ``impl='pallas'``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 
@@ -33,6 +34,15 @@ from repro.models.common import SEQ_CACHE_KEYS, cache_leaf_key
 
 def _is_seq(path) -> bool:
     return cache_leaf_key(path) in SEQ_CACHE_KEYS
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _set_lane_rows(pools, rows, lane):
+    """Write each row (layers, 1, *tail) into ``lane`` of its state pool
+    (layers, lanes, *tail), all in one program.  The pools are donated, so
+    each row is written in place and no second copy of the state exists."""
+    return [jax.lax.dynamic_update_slice_in_dim(p, r.astype(p.dtype), lane, 1)
+            for p, r in zip(pools, rows)]
 
 
 # ---------------------------------------------------------------------------
@@ -765,23 +775,28 @@ class PagedKVCache:
                     pc = jnp.pad(pc, pad)
                 paged = pc.reshape((reps, len(dst), ps) + pc.shape[3:])
                 return pool.at[:, pages_arr].set(paged.astype(pool.dtype))
-            if lane is None:
-                return pool
-            return pool.at[:, lane].set(pc[:, 0].astype(pool.dtype))
+            return pool
 
         self.pools = jax.tree_util.tree_map_with_path(leaf, self.pools, cache)
+        if lane is not None:
+            self.write_state(lane, cache)
 
     @pool_mutator("pools")
     def write_state(self, lane: int, cache) -> None:
-        """Copy only the recurrent-state leaves of a held prefill cache into
-        ``lane``'s row (the lane was not known at prefill time)."""
-
-        def leaf(path, pool, pc):
-            if _is_seq(path):
-                return pool
-            return pool.at[:, lane].set(pc[:, 0].astype(pool.dtype))
-
-        self.pools = jax.tree_util.tree_map_with_path(leaf, self.pools, cache)
+        """Copy only the recurrent-state leaves of a held prefill cache
+        (leaves (layers, 1, *tail)) into ``lane``'s row, in place: the old
+        state leaves are donated to the write."""
+        flat, treedef = jax.tree_util.tree_flatten_with_path(self.pools)
+        src = jax.tree_util.tree_leaves(cache)
+        idx = [i for i, (path, _) in enumerate(flat) if not _is_seq(path)]
+        if not idx:
+            return
+        leaves = [x for _, x in flat]
+        new = _set_lane_rows([leaves[i] for i in idx], [src[i] for i in idx],
+                             lane)
+        for i, x in zip(idx, new):
+            leaves[i] = x
+        self.pools = jax.tree_util.tree_unflatten(treedef, leaves)
 
     def has_state_leaves(self) -> bool:
         found = []

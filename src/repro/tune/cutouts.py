@@ -68,7 +68,8 @@ def _ssd_args(rng: np.random.Generator, cfg, hs: int, ps_: int, ns: int,
     dt = jnp.asarray(rng.normal(size=(1, ss, hs)), jnp.float32)
     a_log = jnp.asarray(rng.normal(size=(hs,)), jnp.float32)
     d_skip = jnp.asarray(rng.normal(size=(hs,)), jnp.float32)
-    return (cfg, xh, bb, cc, dt, a_log, d_skip)
+    dt_bias = jnp.asarray(rng.normal(size=(hs,)), jnp.float32)
+    return (cfg, xh, bb, cc, dt, a_log, d_skip, dt_bias)
 
 
 def _ssd_build(rng: np.random.Generator) -> tuple:

@@ -3,8 +3,8 @@
 The TPU compiler is installed even where no chip is attached: it compiles
 for a *described* ``v5e:2x2`` topology, refusing what the chip's compiler
 would refuse (unaligned blocks, unsupported primitives, VMEM overflow).
-Nothing runs.  Widths are the served ones — qwen2.5-3b attention and VGG16
-convolutions at 224 px.  The topology is described only inside the fixture
+Nothing runs.  Widths are the served ones — qwen2.5-3b and granite-4.0-h-micro
+attention, a granite SSD prefill chunk, and VGG16 convolutions at 224 px.  The topology is described only inside the fixture
 below, never at import: one process at a time may load the TPU library.
 """
 import os
@@ -64,6 +64,48 @@ def test_paged_attention_compiles(one_chip):
     # the benchmark's kernel reading finds the kernel's events by this name
     assert re.search(r"%paged_attention(\.\d+)? = \S+ custom-call\(.*"
                      r'custom_call_target="tpu_custom_call"', hlo)
+
+
+# granite-4.0-h-micro: 32 query heads over 8 KV heads of 64 (4 queries a KV
+# head; the wrapper pads them to 8 rows and 128 lanes), NoPE scale 1/64
+G_H, G_HKV, G_D = 32, 8, 64
+
+
+def test_paged_attention_compiles_head_dim_64(one_chip):
+    hlo = _compile(
+        lambda q, k, v, bt, ln: ops.paged_attention(q, k, v, bt, ln,
+                                                    scale=1 / 64,
+                                                    interpret=False),
+        one_chip,
+        ((LANES, G_H, G_D), BF16), ((N_PAGES, PS, G_HKV, G_D), BF16),
+        ((N_PAGES, PS, G_HKV, G_D), BF16),
+        ((LANES, MAX_LEN // PS), jnp.int32), ((LANES,), jnp.int32),
+    )
+    assert re.search(r"%paged_attention(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', hlo)
+
+
+def test_ssd_prefill_chunk_compiles_granite_widths(one_chip):
+    """One 256-token SSD chunk of a granite mamba layer: 64 heads of 64,
+    state 128, the (Q, Q, H) decay formed exactly and every contraction at
+    HIGHEST precision."""
+    from repro.configs.base import SSMConfig
+    from repro.models.ssm import ssd_chunked
+
+    class Cfg:
+        ssm = SSMConfig(d_state=128, d_conv=4, expand=2, head_dim=64,
+                        chunk=256)
+
+    q, h, p, n = 256, 64, 64, 128
+    hlo = _compile(
+        lambda x, b, c, dt, a, d, bias, h0: ssd_chunked(
+            Cfg, x, b, c, dt, a, d, bias, h0),
+        one_chip,
+        ((1, q, h, p), BF16), ((1, q, n), BF16), ((1, q, n), BF16),
+        ((1, q, h), BF16), ((h,), F32), ((h,), F32), ((h,), F32),
+        ((1, h, p, n), F32),
+    )
+    assert "dot" in hlo or "convolution" in hlo
 
 
 def test_flash_attention_compiles(one_chip):

@@ -57,6 +57,7 @@ def test_arch_smoke_forward_and_train_step(arch):
 @pytest.mark.parametrize("arch", [
     "qwen2.5-3b", "gemma-7b", "deepseek-v3-671b", "qwen3-moe-235b-a22b",
     "mamba2-130m", "recurrentgemma-9b", "whisper-large-v3", "qwen3-32b",
+    "granite-4.0-h-micro",
 ])
 def test_prefill_decode_matches_forward(arch):
     """Teacher-forced decode over the prompt reproduces the full forward's

@@ -243,9 +243,10 @@ def test_preemption_then_resume_reproduces_tokens(served):
 # ---------------------------------------------------------------------------
 
 # one arch per attention-state family the paged engine serves: GQA attention,
-# MLA absorbed latents, SSD recurrent state, RG-LRU hybrid (rec+local attn)
+# MLA absorbed latents, SSD recurrent state, RG-LRU hybrid (rec+local attn),
+# SSD + global NoPE attention hybrid
 PAGED_FAMILIES = ["qwen2.5-3b", "deepseek-v3-671b", "mamba2-130m",
-                  "recurrentgemma-9b"]
+                  "recurrentgemma-9b", "granite-4.0-h-micro"]
 
 
 def _family_model(arch):
@@ -313,7 +314,7 @@ def test_paged_decode_path_tokens_match_gather(arch):
 
 @pytest.mark.parametrize("arch",
                          ["deepseek-v3-671b", "mamba2-130m",
-                          "recurrentgemma-9b"])
+                          "recurrentgemma-9b", "granite-4.0-h-micro"])
 def test_chunked_prefill_matches_whole_prompt_every_family(arch):
     """The MLA absorbed-extend and SSD/RG-LRU stepped-state extend close the
     prefill_chunk gap: chunked == whole-prompt serving for every family
