@@ -270,7 +270,7 @@ class ServeTracer(Tracer):
         self.EV_STEP = self.register("engine.step", ("step",))
         # the decode loop's step, in order: lane fill, page checks, then
         # the decode batch — its inputs' upload, the program's enqueue, the
-        # wait for its logits, their copy to the host, the per-lane choice
+        # wait for its tokens, their copy to the host, the per-lane choice
         self.EV_LANES_FILL = self.register("lanes.fill", ())
         # inside lanes.fill: one lane's recurrent state written in place
         self.EV_LANES_STATE = self.register("lanes.state", ("lane",))

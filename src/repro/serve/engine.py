@@ -342,10 +342,14 @@ class ServeEngine:
         self._h_step = m.histogram("step_latency_s")
         self._h_queue = m.histogram("queue_wait_s")
         # the decode step in three: step start to the program's enqueue,
-        # the host blocked on the device, then logits fetch and sampling
+        # the host blocked on the device, then the token fetch and each
+        # lane's bookkeeping
         self._h_prep = m.histogram("decode.prep_s")
         self._h_wait = m.histogram("decode.wait_s")
         self._h_sample = m.histogram("decode.sample_s")
+        # decode lanes whose token was drawn from their logits row (sampled
+        # at a temperature) rather than taken from the program's greedy choice
+        self._c_host_rows = m.counter("decode.host_rows")
         # the decode loop's time writing a lane's recurrent state at its
         # assignment (families with per-lane state only)
         self._h_state = m.histogram("lanes.state_write_s")
@@ -393,6 +397,10 @@ class ServeEngine:
     # -- jitted pieces --------------------------------------------------------
 
     def _decode_impl(self, params, pools, bt, tokens, positions, active):
+        """One decode step over every lane: ``(tokens (B,), logits (B, 1,
+        V), pools)``.  ``tokens`` is each lane's greedy choice, the first
+        maximum over the whole padded vocabulary of the program's own
+        logits, so only B int32 cross to the host for greedy lanes."""
         if self.ecfg.decode_path == "gather":
             # fallback oracle: materialize the dense per-lane views, decode,
             # scatter the written column back into the pools
@@ -403,13 +411,16 @@ class ServeEngine:
             pools = absorb_decode(
                 pools, new_views, bt, positions, active, self.cache.page_size
             )
-            return logits, pools
-        # zero-materialization path: the model reads/writes the page pools
-        # through the block table (attn_decode_paged / mla_decode_paged)
-        return self.model.decode_step_paged(
-            params, pools, bt, tokens, positions, active, self.rules,
-            attn_impl=self.ecfg.attn_impl,
-        )
+        else:
+            # zero-materialization path: the model reads/writes the page
+            # pools through the block table (attn_decode_paged /
+            # mla_decode_paged)
+            logits, pools = self.model.decode_step_paged(
+                params, pools, bt, tokens, positions, active, self.rules,
+                attn_impl=self.ecfg.attn_impl,
+            )
+        choice = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        return choice, logits, pools
 
     def _prefill_impl(self, params, tokens):
         return self.model.prefill(params, tokens, self.rules)
@@ -1049,29 +1060,32 @@ class ServeEngine:
                   jnp.asarray(positions), jnp.asarray(active))
         tr.end(tr.EV_DECODE_INPUTS)
         tr.begin(tr.EV_DECODE_DISPATCH)
-        logits, self.cache.pools = self._decode(
+        choice, logits, self.cache.pools = self._decode(
             self.params, self.cache.pools, *inputs)
         tr.end(tr.EV_DECODE_DISPATCH)
         t_sent = obs_clock.monotonic()
         tr.begin(tr.EV_DECODE_WAIT)
-        logits = logits[:, 0].block_until_ready()
+        choice.block_until_ready()
         tr.end(tr.EV_DECODE_WAIT)
         t_ready = obs_clock.monotonic()
         tr.begin(tr.EV_DECODE_FETCH)
-        logits = np.asarray(logits, np.float32)
+        choice = np.asarray(choice)
         tr.end(tr.EV_DECODE_FETCH)
         tr.begin(tr.EV_DECODE_SAMPLE)
-        done = 0
+        done = host_rows = 0
         for lane in sorted(list(s.running)):
             st = s.running[lane]
             req = st.req
             if req.temperature > 0 and key is not None:
+                # this lane's row, read on the device: the whole logits
+                # never cross to the host
                 key, sub = jax.random.split(key)
                 tok = int(jax.random.categorical(
-                    sub, jnp.asarray(logits[lane]) / req.temperature
+                    sub, logits[lane, 0].astype(jnp.float32) / req.temperature
                 ))
+                host_rows += 1
             else:
-                tok = int(np.argmax(logits[lane]))
+                tok = int(choice[lane])
             req.out_tokens.append(tok)
             st.length += 1
             st.pending_token = tok
@@ -1091,6 +1105,7 @@ class ServeEngine:
         kv_share = self._kv_block_share(positions, active)
         with self._lock:
             self._c_decode.inc(done)
+            self._c_host_rows.inc(host_rows)
             self._c_lane_step.inc(n_active)
             self._h_prep.observe(t_sent - t_step)
             self._h_wait.observe(t_ready - t_sent)

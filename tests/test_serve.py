@@ -353,6 +353,161 @@ def test_paged_decode_pallas_impl_serves_identically(served):
     assert want == got
 
 
+# ---------------------------------------------------------------------------
+# The token choice: greedy inside the decode program, temperature per row
+# ---------------------------------------------------------------------------
+
+
+def _spy_decode(eng, on_step):
+    """Wrap the engine's jitted decode step: ``on_step(choice, rows)`` sees
+    each step's greedy choice and its logits rows as the host would have
+    copied them (the float32 cast of the program's bf16), before the engine
+    picks any lane's token."""
+    decode = eng._decode
+
+    def spy(*args):
+        choice, logits, pools = decode(*args)
+        on_step(np.asarray(choice), np.asarray(logits[:, 0], np.float32))
+        return choice, logits, pools
+
+    eng._decode = spy
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "granite-4.0-h-micro"])
+def test_greedy_tokens_are_the_argmax_of_the_programs_logits(arch):
+    """Ragged greedy requests with queueing and refill: every token a lane
+    is served from a decode step is ``np.argmax`` of the float32 cast of
+    the logits row the program returned for it, which is also the program's
+    own choice; no lane's row is read on the host."""
+    from repro.serve import AdmissionConfig
+
+    cfg = get_arch(arch).reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    eng = ServeEngine(model, params, EngineConfig(
+        batch_slots=3, max_len=64,
+        admission=AdmissionConfig(prefill_chunk=8)), RULES)
+    want = {}
+
+    def on_step(choice, rows):
+        for lane, st in eng.sched.running.items():
+            tok = int(np.argmax(rows[lane]))
+            assert int(choice[lane]) == tok
+            want[(st.req.uid, len(st.req.out_tokens))] = tok
+
+    _spy_decode(eng, on_step)
+    reqs = _reqs(cfg, n=5, plen=4, max_new=6, ragged=True, seed=3)
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    got = {(uid, i): reqs[uid].out_tokens[i] for uid, i in want}
+    assert got == want
+    counters = eng.metrics.snapshot()["counters"]
+    assert len(want) == counters["decode_tokens"] == 5 * 5
+    assert counters["decode.host_rows"] == 0
+
+
+class _CraftedLogits:
+    """A served model with its decode logits replaced by ``rows`` (B, V):
+    ties, and maxima in the padding columns past ``vocab_size``."""
+
+    def __init__(self, model, rows):
+        self._model, self._rows = model, rows
+
+    def __getattr__(self, name):
+        return getattr(self._model, name)
+
+    def _crafted(self, out):
+        logits, state = out
+        return jnp.asarray(self._rows, logits.dtype)[:, None], state
+
+    def decode_step(self, *args, **kw):
+        return self._crafted(self._model.decode_step(*args, **kw))
+
+    def decode_step_paged(self, *args, **kw):
+        return self._crafted(self._model.decode_step_paged(*args, **kw))
+
+
+@pytest.mark.parametrize("decode_path", ["paged", "gather"])
+def test_device_choice_breaks_ties_like_the_host(served, decode_path):
+    """A vocabulary of 500 pads to 512 columns.  Exact ties go to the first
+    index and the padding columns take part, as ``np.argmax`` over the host
+    copy did: the program's choice equals that rule on every row."""
+    from repro.serve import CacheConfig
+
+    cfg, _, _ = served
+    cfg = dataclasses.replace(cfg, vocab_size=500)
+    assert cfg.padded_vocab == 512
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    rows = np.zeros((4, 512), np.float32)      # row 0: all tied -> 0
+    rows[1, [3, 7, 300]] = 1.0                 # -> 3
+    rows[2, 0], rows[2, [505, 506]] = 1.0, 2.0  # padding column -> 505
+    rows[3, [100, 510]] = 3.0                  # real before padding -> 100
+    eng = ServeEngine(model, params, EngineConfig(
+        batch_slots=4, max_len=32,
+        cache=CacheConfig(decode_path=decode_path)), RULES)
+    eng.model = _CraftedLogits(eng.model, rows)
+    bt, toks, positions, active = eng.decode_inputs()
+    choice, logits, eng.cache.pools = eng._decode(
+        eng.params, eng.cache.pools, jnp.asarray(bt), jnp.asarray(toks),
+        jnp.asarray(positions), jnp.asarray(active))
+    host = np.argmax(np.asarray(logits[:, 0], np.float32), axis=-1)
+    assert choice.dtype == jnp.int32 and choice.shape == (4,)
+    assert np.asarray(choice).tolist() == host.tolist() == [0, 3, 505, 100]
+
+
+def test_temperature_lanes_keep_their_random_stream(served):
+    """Greedy and temperature lanes side by side under fixed step keys: a
+    temperature lane's token is the host rule's draw, ``categorical`` of
+    its float32 logits row over the temperature with one key split per
+    sorted temperature lane, and a greedy lane's is the argmax.
+    ``decode.host_rows`` counts exactly the temperature lanes' steps."""
+    from repro.serve import AdmissionConfig
+
+    cfg, model, params = served
+    eng = ServeEngine(model, params, EngineConfig(
+        batch_slots=3, max_len=64,
+        admission=AdmissionConfig(async_prefill=False)), RULES)
+    reqs = _reqs(cfg, n=5, plen=5, max_new=7, ragged=True, seed=11)
+    # the reduced model's top logit leads the next by 20-50: temperatures
+    # of that order make the draws leave the argmax
+    for r, temp in zip(reqs, (0.0, 10.0, 0.0, 40.0, 80.0)):
+        r.temperature = temp
+    want, greedy, step_key = {}, {}, [None]
+
+    def on_step(choice, rows):
+        key = step_key[0]
+        for lane in sorted(eng.sched.running):
+            st = eng.sched.running[lane]
+            req = st.req
+            if req.temperature > 0:
+                key, sub = jax.random.split(key)
+                tok = int(jax.random.categorical(
+                    sub, jnp.asarray(rows[lane]) / req.temperature))
+                greedy[(req.uid, len(req.out_tokens))] = int(
+                    np.argmax(rows[lane]))
+            else:
+                tok = int(np.argmax(rows[lane]))
+            want[(req.uid, len(req.out_tokens))] = tok
+
+    _spy_decode(eng, on_step)
+    for r in reqs:
+        eng.submit(r)
+    key = jax.random.key(2**31 + 17)
+    while eng.load:
+        key, step_key[0] = jax.random.split(key)
+        eng.step(step_key[0])
+    got = {(uid, i): reqs[uid].out_tokens[i] for uid, i in want}
+    assert got == want
+    assert len(greedy) == 3 * 6
+    counters = eng.metrics.snapshot()["counters"]
+    assert counters["decode.host_rows"] == len(greedy)
+    assert counters["decode_tokens"] == len(want) == 5 * 6
+    # some draws leave the argmax, so the test sees the temperature path
+    assert any(got[k] != tok for k, tok in greedy.items())
+
+
 def test_engine_rejects_unknown_decode_path(served):
     cfg, model, params = served
     with pytest.raises(ValueError):

@@ -253,11 +253,12 @@ def _serve_and_capture(model, params, prompts, max_new, prefill_chunk):
         return logits, tree
 
     def spy_decode(params, pools, bt, toks, positions, active):
-        logits, pools = decode(params, pools, bt, toks, positions, active)
+        choice, logits, pools = decode(params, pools, bt, toks, positions,
+                                       active)
         for lane, st in eng.sched.running.items():
             seen[("decode", st.req.uid, int(positions[lane]))] = np.asarray(
                 logits[lane, 0], np.float32)
-        return logits, pools
+        return choice, logits, pools
 
     eng._extend, eng._decode = spy_extend, spy_decode
     reqs = [Request(uid=i, prompt=p, max_new_tokens=max_new)
