@@ -39,13 +39,6 @@ import sys
 # run) rather than loosening the gate; BENCH_GATE_TOLERANCE exists for a
 # deliberate temporary override, not as a knob to silence a regression.
 TOLERANCE = float(os.environ.get("BENCH_GATE_TOLERANCE", "0.8"))
-# per-key overrides for metrics that are absolute wall times rather than
-# same-machine ratios: cube_recovery_s is tens of milliseconds of pipe
-# drains + adopt-shadow round-trips, so honest run-to-run noise is 2-3x
-# even on an idle host.  0.25 gates it at 4x the committed baseline —
-# catching a collapse into seconds-scale recovery without flaking on
-# scheduler jitter the 20% ratio band was never meant to absorb.
-TOLERANCE_OVERRIDES = {"cube_recovery_s": 0.25}
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 SERVE_BASELINE = ROOT / "BENCH_serve.json"
 KERNEL_BASELINE = ROOT / "BENCH_kernels.json"
@@ -69,17 +62,7 @@ GATED_SERVE = ("speedup", "paged_vs_gather_speedup",
                # tokens served from the radix index (deterministic > 0.5 by
                # the bench's two-phase construction) and the throughput
                # ratio vs re-prefilling every repeat
-               "prefix_hit_rate", "prefix_vs_none_tokens_per_s",
-               # multi-process cube serving: N worker processes behind
-               # CubeProcRouter vs one in-process engine on the same
-               # workload (IPC + per-process XLA overhead keeps this below
-               # 1.0 on few-core CI hosts; the gate holds the plumbing
-               # steady, not an absolute win)
-               "multicube_vs_single_tokens_per_s")
-# lower-is-better serve keys, gated/trended separately from the speedups:
-# seconds from detecting a SIGKILLed cube to every stranded request
-# re-routed (shadow adopted or prompt re-submitted) on a survivor
-GATED_SERVE_LOWER = ("cube_recovery_s",)
+               "prefix_hit_rate", "prefix_vs_none_tokens_per_s")
 GATED_KERNELS = ("attn.flash_xla.oracle_ratio", "attn.paged_decode.oracle_ratio",
                  "ssd.chunked.oracle_ratio", "moe.dispatch.oracle_ratio",
                  # cutout-autotuner wins: tuned-vs-default timing of the
@@ -103,20 +86,8 @@ def run_serve() -> dict:
     sb = serve_bench.bench_swap_batch()
     ob = serve_bench.bench_obs_overhead(size="gate")
     px = serve_bench.bench_prefix(size="gate")
-    mc = serve_bench.bench_multicube(size="gate", kill_cube=True)
     paged = r["decode_paths"]["paged"]
     return {
-        # multi-process cubes: throughput ratio vs the single engine, and
-        # the kill-mid-drive recovery time (chaos path runs on every gate)
-        "multicube_vs_single_tokens_per_s":
-            mc["multicube_vs_single_tokens_per_s"],
-        "cube_recovery_s": mc["cube_recovery_s"],
-        "multicube_tokens_identical": mc["multicube_tokens_identical"],
-        "multicube_tok_s": mc["multi"]["tok_s"],
-        "singlecube_tok_s": mc["single"]["tok_s"],
-        "multicube_stranded": mc["stranded"],
-        "multicube_adopted": mc["adopted"],
-        "multicube_resubmitted": mc["resubmitted"],
         # prefix sharing: replay hit rate + reuse-vs-reprefill throughput
         "prefix_hit_rate": px["prefix_hit_rate"],
         "prefix_vs_none_tokens_per_s": px["prefix_vs_none_tokens_per_s"],
@@ -219,13 +190,12 @@ def check(current: dict, baseline: dict, gated, label: str,
             failures.append(f"{label}: metric {key!r} missing "
                             f"(baseline={base}, current={cur})")
             continue
-        tol = TOLERANCE_OVERRIDES.get(key, TOLERANCE)
         if lower_is_better:
-            limit = base / tol
+            limit = base / TOLERANCE
             bad = cur > limit
             bound_name = "ceiling"
         else:
-            limit = tol * base
+            limit = TOLERANCE * base
             bad = cur < limit
             bound_name = "floor"
         status = "REGRESSED" if bad else "ok"
@@ -267,9 +237,6 @@ def trend(out_serve: str, out_kernels: str) -> int:
             reports[label] = json.loads(p.read_text())
     for label, base_path, gated, symmetric, lower_is_better in (
         ("serve", SERVE_BASELINE, GATED_SERVE, True, False),
-        # cube recovery time is an absolute duration, not a ratio: alarm on
-        # upward collapse only (faster recovery is never a stale baseline)
-        ("serve", SERVE_BASELINE, GATED_SERVE_LOWER, False, True),
         ("kernels", KERNEL_BASELINE, GATED_KERNELS, False, True),
     ):
         cur = reports[label]
@@ -283,7 +250,7 @@ def trend(out_serve: str, out_kernels: str) -> int:
                                 f"(baseline={b}, current={c})")
                 continue
             drift = c / b - 1.0
-            band = 1.0 - TOLERANCE_OVERRIDES.get(key, TOLERANCE)
+            band = 1.0 - TOLERANCE
             # one-sided checks alarm on the WORSE direction only: upward
             # for lower-is-better metrics, downward otherwise
             one_sided = drift if lower_is_better else -drift
@@ -398,9 +365,6 @@ def main(argv=None) -> int:
         failures.append("serve: traced/untraced token identity broken")
     if not serve.get("prefix_tokens_identical"):
         failures.append("serve: prefix-sharing on/off token identity broken")
-    if not serve.get("multicube_tokens_identical"):
-        failures.append("serve: multi-process cube router token identity "
-                        "broken (vs single in-process engine)")
     obs_ratio = serve.get("obs_overhead_tokens_per_s")
     if obs_ratio is not None and obs_ratio < OBS_OVERHEAD_FLOOR:
         failures.append(
@@ -409,8 +373,6 @@ def main(argv=None) -> int:
         )
     serve_base = json.loads(SERVE_BASELINE.read_text())
     failures += check(serve, serve_base, GATED_SERVE, "serve")
-    failures += check(serve, serve_base, GATED_SERVE_LOWER, "serve",
-                      lower_is_better=True)
     failures += check(kernels, json.loads(KERNEL_BASELINE.read_text()),
                       GATED_KERNELS, "kernels", lower_is_better=True)
     if failures:

@@ -862,135 +862,6 @@ def bench_trace(out_path: str, seed: int = 0, smoke: bool = False) -> dict:
     }
 
 
-def bench_multicube(smoke: bool = False, seed: int = 0,
-                    size: str | None = None, n_cubes: int = 2,
-                    kill_cube: bool = False,
-                    recovery_trace: str | None = None) -> dict:
-    """Multi-process cube serving vs one in-process engine, with optional
-    mid-drive chaos: the same submit-everything workload through (a) a
-    single ``ServeEngine`` and (b) a ``CubeProcRouter`` running one worker
-    process per cube; tokens must match bit-for-bit (greedy decode, every
-    worker builds identical params from the arch id).
-
-    ``kill_cube=True`` SIGKILLs cube 0 once it has demonstrably decoded a
-    few steps: the router must re-route its in-flight requests (adopt a
-    committed shadow checkpoint from host-tier pages, or re-submit from
-    the prompt) and the surviving cube's streams must still be identical —
-    the CI chaos smoke and the ``cube_recovery_s`` gate key.  The recovery
-    log (the CI artifact) records what was stranded/adopted/resubmitted.
-    """
-    import dataclasses as _dc
-    import threading as _threading
-
-    import jax
-
-    from repro.configs import get_arch
-    from repro.models import build_model
-    from repro.models.common import AxisRules, DEFAULT_RULES
-    from repro.serve import (AdmissionConfig, CacheConfig, CubeProcRouter,
-                             EngineConfig, Request, ServeEngine)
-    from repro.serve.cube_proc import refuse_on_tpu
-
-    # before the in-process oracle engine takes the chip the workers need
-    refuse_on_tpu()
-    size = size or ("smoke" if smoke else "full")
-    n, max_new = {"smoke": (4, 6), "gate": (8, 8)}.get(size, (12, 10))
-    arch = "qwen2.5-3b"
-    ecfg = EngineConfig(
-        batch_slots=2, max_len=32,
-        cache=CacheConfig(page_size=4, n_pages=16, preempt_policy="swap",
-                          swap_token_cost=0.0),
-        admission=AdmissionConfig(async_prefill=False),
-    )
-    rng = np.random.default_rng(seed)
-    cfg = get_arch(arch).reduced()
-    prompts = [rng.integers(0, cfg.vocab_size, size=(7,)).astype(np.int32)
-               for _ in range(n)]
-
-    def reqs():
-        return [Request(uid=i, prompt=prompts[i], max_new_tokens=max_new)
-                for i in range(n)]
-
-    # single in-process engine: the token oracle and the throughput
-    # denominator (same layer-loop build as the workers)
-    rules = AxisRules(DEFAULT_RULES)
-    model = build_model(_dc.replace(cfg, decode_unroll_layers=False))
-    params = model.init(jax.random.key(0))
-    eng = ServeEngine(model, params, ecfg, rules)
-    single = reqs()
-    t0 = time.perf_counter()
-    for r in single:
-        eng.submit(r)
-    eng.run()
-    single_dt = time.perf_counter() - t0
-    want = {r.uid: list(r.out_tokens) for r in single}
-    single_tokens = sum(len(t) for t in want.values())
-
-    with CubeProcRouter(arch, ecfg, n_cubes=n_cubes,
-                        checkpoint_every=2) as router:
-        multi = reqs()
-        killed_at = [None]
-
-        def chaos():
-            deadline = time.monotonic() + 240.0
-            while time.monotonic() < deadline:
-                if router.detector._count.get(0, 0) >= 3:
-                    killed_at[0] = time.perf_counter()
-                    router.kill_cube(0)
-                    return
-                time.sleep(0.02)
-
-        t0 = time.perf_counter()         # worker startup excluded: the
-        for r in multi:                  # router is already up and ready
-            router.submit(r)
-        killer = None
-        if kill_cube:
-            killer = _threading.Thread(target=chaos, daemon=True)
-            killer.start()
-        done = router.run(timeout=300.0)
-        multi_dt = time.perf_counter() - t0
-        if killer is not None:
-            killer.join(timeout=10.0)
-        tel = router.telemetry()
-        log = list(router.recovery_log)
-
-    got = {r.uid: list(r.out_tokens) for r in done}
-    identical = got == want
-    assert identical, "multicube streams diverged from the single engine"
-    multi_tokens = sum(len(t) for t in got.values())
-    out = {
-        "n_cubes": n_cubes, "requests": n,
-        "single": {"tok_s": single_tokens / single_dt,
-                   "tokens": single_tokens, "seconds": single_dt},
-        "multi": {"tok_s": multi_tokens / multi_dt,
-                  "tokens": multi_tokens, "seconds": multi_dt,
-                  "routed": tel["total_routed"]},
-        "multicube_vs_single_tokens_per_s":
-            (multi_tokens / multi_dt) / (single_tokens / single_dt),
-        "multicube_tokens_identical": identical,
-        "recovery_log": log,
-    }
-    if kill_cube:
-        deaths = [e for e in log if e["event"] == "cube_dead"]
-        assert len(deaths) == 1, "chaos run must record exactly one death"
-        ev = deaths[0]
-        assert set(ev["adopted"]) | set(ev["resubmitted"]) == set(
-            ev["stranded"]), "recovery lost track of a stranded request"
-        assert killed_at[0] is not None
-        out["cube_recovery_s"] = ev["recovery_s"]
-        out["killed_cube"] = ev["cube"]
-        out["stranded"] = len(ev["stranded"])
-        out["adopted"] = len(ev["adopted"])
-        out["resubmitted"] = len(ev["resubmitted"])
-    if recovery_trace:
-        with open(recovery_trace, "w") as f:
-            json.dump({"recovery_log": log, "telemetry": tel,
-                       "tokens_identical": identical}, f, indent=2,
-                      default=float)
-        out["recovery_trace"] = recovery_trace
-    return out
-
-
 def bench():
     """CSV rows for benchmarks/run.py (small non-smoke run)."""
     r = bench_pair(smoke=True)
@@ -1045,18 +916,6 @@ def main(argv=None):
                          "and write its Perfetto/Chrome trace here; the "
                          "trace is validated against the scheduler state "
                          "machine before the bench exits")
-    ap.add_argument("--cubes", type=int, default=0,
-                    help="also bench multi-process cube serving: N worker "
-                         "processes behind CubeProcRouter vs one in-process "
-                         "engine, token identity asserted (0 = skip)")
-    ap.add_argument("--kill-cube", action="store_true",
-                    help="with --cubes: SIGKILL cube 0 mid-drive and assert "
-                         "recovery completes with surviving-cube token "
-                         "identity (the CI chaos smoke); reports "
-                         "cube_recovery_s and writes the recovery trace")
-    ap.add_argument("--recovery-trace", metavar="OUT.json",
-                    default="recovery_trace.json",
-                    help="recovery-log artifact path for --kill-cube runs")
     ap.add_argument("--out", default="serve_bench.json")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
@@ -1084,11 +943,6 @@ def main(argv=None):
     if args.trace:
         results["trace"] = bench_trace(args.trace, seed=args.seed,
                                        smoke=args.smoke)
-    if args.cubes:
-        results["multicube"] = bench_multicube(
-            smoke=args.smoke, seed=args.seed, n_cubes=args.cubes,
-            kill_cube=args.kill_cube,
-            recovery_trace=args.recovery_trace if args.kill_cube else None)
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2, default=float)
     d = results["dense"]
@@ -1160,19 +1014,6 @@ def main(argv=None):
               f"{tr['trace_events']} events validated against the phase "
               f"state machine ({tr['preemptions']} preemptions) "
               f"-> {tr['out']}")
-    if "multicube" in results:
-        mc = results["multicube"]
-        print(f"multicube: {mc['n_cubes']} worker procs "
-              f"{mc['multi']['tok_s']:.2f} tok/s vs single "
-              f"{mc['single']['tok_s']:.2f} tok/s "
-              f"({mc['multicube_vs_single_tokens_per_s']:.2f}x, "
-              "tokens identical)")
-        if "cube_recovery_s" in mc:
-            print(f"multicube: cube {mc['killed_cube']} killed mid-drive — "
-                  f"{mc['stranded']} stranded, {mc['adopted']} adopted from "
-                  f"shadows, {mc['resubmitted']} resubmitted, recovery "
-                  f"{mc['cube_recovery_s']*1e3:.1f} ms "
-                  f"-> {mc.get('recovery_trace')}")
     print(f"speedup: {results['speedup']:.2f}x  -> {args.out}")
     return results
 

@@ -13,12 +13,6 @@ documented in ``serve/engine.py`` / ``serve/admission.py``:
 * ``@admission_api`` — a method in the admission pipeline's call graph
   (worker thread): it may reserve/free pages *under the lock* and compute
   into private buffers, but must never reach a ``pool_mutator("pools")``.
-* ``@cube_transport`` — a function on the inter-cube wire path
-  (``serve/cube_proc.py``): it frames/ships messages between processes and
-  must never touch engine-owned device state — no ``pool_mutator("pools")``
-  and no ``@decode_loop_only`` entry.  Engine-side migration landing
-  (``migrate_put`` → host tier, under the lock) is NOT transport: the
-  boundary is "the wire moves bytes, the engine moves pages".
 
 The static rule ``repro.analysis.rules.sole_writer`` reads these markers
 from the AST (undeclared mutations, admission-reachable pools writes); the
@@ -36,7 +30,7 @@ from typing import Any, TypeVar
 from . import sanitizer
 
 __all__ = ["pool_mutator", "decode_loop_only", "admission_api",
-           "cube_transport", "MUTATOR_KINDS"]
+           "MUTATOR_KINDS"]
 
 F = TypeVar("F", bound=Callable[..., Any])
 
@@ -112,23 +106,3 @@ def admission_api(fn: F) -> F:
     fn._repro_admission_api = True                  # type: ignore[attr-defined]
     return fn
 
-
-def cube_transport(fn: F) -> F:
-    """Declare a function on the inter-cube wire path: while it runs (on
-    this thread), any pools mutation or ``@decode_loop_only`` entry is a
-    cross-process ownership violation — the transport moves bytes, never
-    pages.  Static taint root for ``repro.analysis.rules.cube_boundary``;
-    runtime scope tracked per-thread by the sanitizer."""
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        if not sanitizer.enabled():
-            return fn(*args, **kwargs)
-        sanitizer.on_transport_entry(fn.__name__)
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            sanitizer.on_transport_exit()
-
-    wrapper._repro_cube_transport = True            # type: ignore[attr-defined]
-    return wrapper                                  # type: ignore[return-value]

@@ -1,7 +1,6 @@
 """Rule registry for repro-lint.  Each rule module exposes ``RULES``
 (the rule-id strings it can emit) and ``check(files) -> list[Finding]``."""
 from . import (
-    cube_boundary,
     jax_under_lock,
     obs_hot_path,
     pallas_trace,
@@ -11,6 +10,6 @@ from . import (
 )
 
 ALL_RULE_MODULES = [jax_under_lock, sole_writer, phase_transitions,
-                    pallas_trace, obs_hot_path, tune_lookup, cube_boundary]
+                    pallas_trace, obs_hot_path, tune_lookup]
 
 ALL_RULE_IDS = [rid for mod in ALL_RULE_MODULES for rid in mod.RULES]

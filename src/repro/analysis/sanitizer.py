@@ -47,9 +47,6 @@ __all__ = [
     "register_engine",
     "register_admission_thread",
     "unregister_admission_thread",
-    "on_transport_entry",
-    "on_transport_exit",
-    "in_transport",
     "note_grant",
     "note_release",
     "verify_grant",
@@ -184,37 +181,11 @@ def unregister_admission_thread(engine: Any) -> None:
     rec.admission_idents.discard(threading.get_ident())
 
 
-# -- cube-transport scope (the cross-process ownership boundary) ------------
-
-# per-thread nesting depth of @cube_transport frames: while > 0, this
-# thread is moving wire bytes between cube processes and must not touch
-# engine-owned device state (pools writes, decode-loop entries)
-_transport = threading.local()
-
-
-def on_transport_entry(name: str) -> None:
-    _transport.depth = getattr(_transport, "depth", 0) + 1
-    _transport.name = name
-
-
-def on_transport_exit() -> None:
-    _transport.depth = max(0, getattr(_transport, "depth", 1) - 1)
-
-
-def in_transport() -> bool:
-    return getattr(_transport, "depth", 0) > 0
-
-
 # -- decorator hooks (ownership.py calls these when enabled) ----------------
 
 
 def on_decode_loop_entry(obj: Any, name: str) -> None:
     rec = _record_for(_anchor(obj))
-    if in_transport():
-        _log(rec, f"VIOLATION {name}")
-        _raise(rec, f"@decode_loop_only method {name!r} entered from inside "
-                    f"cube-transport frame {getattr(_transport, 'name', '?')!r}"
-                    " — the wire layer must never drive the decode loop")
     if threading.get_ident() in rec.admission_idents:
         _log(rec, f"VIOLATION {name}")
         _raise(rec, f"@decode_loop_only method {name!r} called from an "
@@ -227,10 +198,6 @@ def pre_mutate(obj: Any, kind: str, name: str,
     ident = threading.get_ident()
     _log(rec, f"{kind}:{name}", f"pages={pages}" if pages else "")
     if kind == "pools":
-        if in_transport():
-            _raise(rec, f"pool mutation {name!r} from inside cube-transport "
-                        f"frame {getattr(_transport, 'name', '?')!r} — the "
-                        "wire layer moves bytes, never pages")
         if ident in rec.admission_idents:
             _raise(rec, f"pool mutation {name!r} from admission-pipeline "
                         "thread (decode loop is the sole pools writer)")

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 MODES = ("none", "bf16", "int8")
 
@@ -69,30 +68,6 @@ def decompress_tree(tree, scales, mode: str = "bf16"):
         tree,
         scales,
     )
-
-
-def wire_pack(tree, mode: str = "none") -> dict:
-    """Lower a pytree to a self-describing, picklable wire message.
-
-    ``compress_tree`` then ``np.asarray`` every leaf: jax arrays don't
-    pickle across processes, numpy (incl. ml_dtypes bf16) does.  Mode
-    ``none`` is the exact identity — REQUIRED for token/page payloads,
-    where bit-exactness is the whole contract; ``bf16``/``int8`` are for
-    telemetry-grade traffic where wire bytes matter more than the last
-    mantissa bit.  Inverse: :func:`wire_unpack`.
-    """
-    comp, scales = compress_tree(tree, mode)
-    return {
-        "mode": mode,
-        "comp": jax.tree.map(np.asarray, comp),
-        "scales": jax.tree.map(np.asarray, scales),
-    }
-
-
-def wire_unpack(msg: dict):
-    """Decode a :func:`wire_pack` message back to a host (numpy-leaf) tree."""
-    out = decompress_tree(msg["comp"], msg["scales"], msg["mode"])
-    return jax.tree.map(np.asarray, out)
 
 
 def wire_bytes(tree, mode: str = "bf16") -> int:

@@ -60,8 +60,8 @@ class StragglerDetector:
         self._count[host] = self._count.get(host, 0) + 1
 
     def forget(self, host: int) -> None:
-        """Drop a host's report history — called after the router retires a
-        dead cube so it stops dominating the dead/straggler queries."""
+        """Drop a host's report history, so a retired host stops dominating
+        the dead/straggler queries."""
         self._first.pop(host, None)
         self._last.pop(host, None)
         self._count.pop(host, None)

@@ -22,7 +22,6 @@ from repro.models import build_model
 from repro.serve import (
     AdmissionConfig,
     CacheConfig,
-    CubeProcRouter,
     CubeRouter,
     EngineConfig,
     ObsConfig,
@@ -79,14 +78,6 @@ def main(argv=None):
     ap.add_argument("--route",
                     choices=["hash", "least_loaded", "prefix_affinity"],
                     default="least_loaded")
-    ap.add_argument("--multiproc", action="store_true",
-                    help="with --cubes N: one worker PROCESS per cube "
-                         "(serve.cube_proc.CubeProcRouter) with live "
-                         "straggler/dead-cube fault policy, instead of "
-                         "in-process engine replicas")
-    ap.add_argument("--checkpoint-every", type=int, default=4,
-                    help="multiproc: steps between shadow checkpoints "
-                         "forwarded to the backup cube (0 = off)")
     ap.add_argument("--trace", metavar="OUT.json", default=None,
                     help="record request lifecycles + engine events into "
                          "the ring-buffer tracer and write a Perfetto/"
@@ -117,27 +108,20 @@ def main(argv=None):
         obs=ObsConfig(trace=args.trace is not None),
     )
     mesh_ctx = contextlib.nullcontext()
-    if args.cubes > 1 and args.multiproc:
-        # the workers build their own (reduced) model; the parent stays off
-        # the device, and CubeProcRouter refuses to start on a TPU host
-        eng = CubeProcRouter(args.arch, ecfg, n_cubes=args.cubes,
-                             policy=args.route,
-                             checkpoint_every=args.checkpoint_every)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    if args.cubes > 1:
+        devs = jax.devices()
+        eng = CubeRouter(
+            model, params, ecfg, n_cubes=args.cubes, policy=args.route,
+            devices=devs[:args.cubes] if len(devs) >= args.cubes else None,
+        )
     else:
-        model = build_model(cfg)
-        params = model.init(jax.random.key(0))
-        if args.cubes > 1:
-            devs = jax.devices()
-            eng = CubeRouter(
-                model, params, ecfg, n_cubes=args.cubes, policy=args.route,
-                devices=devs[:args.cubes] if len(devs) >= args.cubes else None,
-            )
-        else:
-            mesh = make_host_mesh()
-            mesh_ctx = jax.set_mesh(mesh)
-            rules = arch_rules(cfg, mesh, step="decode",
-                               global_batch=args.slots)
-            eng = ServeEngine(model, params, ecfg, rules)
+        mesh = make_host_mesh()
+        mesh_ctx = jax.set_mesh(mesh)
+        rules = arch_rules(cfg, mesh, step="decode",
+                           global_batch=args.slots)
+        eng = ServeEngine(model, params, ecfg, rules)
     with mesh_ctx:
         rng = np.random.default_rng(0)
         for i in range(args.requests):
@@ -154,14 +138,9 @@ def main(argv=None):
     print(f"{cfg.name}: {len(done)} requests, {toks} tokens, "
           f"{toks/dt:.1f} tok/s")
     print(json.dumps(eng.telemetry(), indent=2, default=float))
-    if args.trace and hasattr(eng, "save_trace"):
+    if args.trace:
         eng.save_trace(args.trace)
         print(f"trace -> {args.trace}")
-    elif args.trace:
-        print("trace: not supported with --multiproc (workers own their "
-              "ring buffers); recovery events land in telemetry instead")
-    if hasattr(eng, "shutdown"):
-        eng.shutdown()
 
 
 if __name__ == "__main__":

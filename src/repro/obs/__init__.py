@@ -7,13 +7,12 @@ Three layers, strictly ordered by cost:
   numpy scalar stores only) with Perfetto export in
   :mod:`repro.obs.export`;
 * :mod:`repro.obs.metrics` — typed counters/gauges/histograms behind a
-  single (shareable) lock, snapshot-consistent, wire-serializable via
-  :mod:`repro.obs.wire`.
+  single (shareable) lock, snapshot-consistent.
 
-`repro.obs.wire` is the only submodule that imports jax at import time
-(a tracer built with ``annotate=True`` imports ``jax.profiler`` then);
-keep it that way so the tracer and metrics stay importable (and cheap)
-everywhere, including under the engine lock.
+No submodule imports jax at import time (a tracer built with
+``annotate=True`` imports ``jax.profiler`` then); keep it that way so the
+tracer and metrics stay importable (and cheap) everywhere, including under
+the engine lock.
 """
 from .clock import ManualClock, monotonic, reset_source, set_source
 from .metrics import (

@@ -11,8 +11,7 @@ telemetry iterated it.
 
 :meth:`MetricsRegistry.snapshot` returns a deep copy: plain ints, floats,
 and fresh lists only.  Mutating a snapshot can never perturb live
-metrics, and the structure serializes through ``dist.collectives`` wire
-codecs (see :mod:`repro.obs.wire`) for future multi-process cubes.
+metrics.
 """
 from __future__ import annotations
 

@@ -9,11 +9,8 @@ MIGRATION.md):
   :class:`CacheConfig` / :class:`AdmissionConfig` / :class:`ObsConfig`
   groups) — the paged two-loop engine;
 * :class:`CubeRouter` — hash / least-loaded / prefix-affinity routing over
-  CUBE_AXIS replicas (in-process);
-* :class:`CubeProcRouter` / :class:`CubeProc` — the same routing surface
-  over one worker *process* per cube, with live straggler/dead-cube policy
-  and put-then-signal KV-page migration (see docs/architecture.md, "Cube
-  network");
+  CUBE_AXIS replicas, one engine per device, in one process (see
+  docs/architecture.md, "Cube network");
 * :class:`Scheduler` / :class:`SchedulerConfig` — admission + preemption;
 * :class:`PagedKVCache` / :class:`PageAllocator` / :class:`PrefixIndex` /
   :class:`PrefixClaim` — the refcounted page pool and the prefix-sharing
@@ -24,7 +21,6 @@ MIGRATION.md):
   proven bit-exact against.
 """
 from .admission import AdmissionPipeline
-from .cube_proc import CubeProc, CubeProcRouter
 from .dense_engine import DenseSlotEngine
 from .engine import (
     AdmissionConfig,
@@ -48,8 +44,6 @@ __all__ = [
     "AdmissionConfig",
     "AdmissionPipeline",
     "CacheConfig",
-    "CubeProc",
-    "CubeProcRouter",
     "CubeRouter",
     "DenseSlotEngine",
     "EngineConfig",

@@ -55,7 +55,7 @@ from .paged_cache import (
     absorb_decode,
     gather_views,
 )
-from .scheduler import RequestState, Scheduler, SchedulerConfig
+from .scheduler import Scheduler, SchedulerConfig
 
 
 @dataclass
@@ -369,21 +369,6 @@ class ServeEngine:
         self.pipeline = AdmissionPipeline(self, ecfg.async_prefill)
         self._idle_since: float | None = None
         self._idle_pipe_mark = -1
-        # inter-cube migration landing zones (serve/cube_proc.py).  Both
-        # follow the one-sided put-then-signal idiom: the *put*
-        # (migrate_put / shadow_put) lands page payloads in the host tier
-        # while the decode loop keeps stepping, then the *signal*
-        # (migrate_signal / shadow_signal) flips ``committed`` — and only
-        # committed entries are ever acted on (poll_migrations /
-        # adopt_shadow), so a sender killed mid-transfer leaves nothing
-        # half-adopted.  _migrations entries become scheduled requests at
-        # the next poll; _shadows are standby checkpoints of requests
-        # running on ANOTHER cube, adopted only if that cube dies.
-        self._migrations: dict[object, dict] = {}
-        self._shadows: dict[int, dict] = {}
-        self._c_migr_in = m.counter("migrate.landed")
-        self._c_migr_resumed = m.counter("migrate.resumed")
-        self._c_migr_fresh = m.counter("migrate.fresh_fallbacks")
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._extend = jax.jit(self._extend_impl, donate_argnums=(1,))
         # whole-prompt prefill, jit-cached per prompt length (the dense v1
@@ -448,239 +433,6 @@ class ServeEngine:
             self.sched.add(req)
             self._cv.notify_all()
         self.pipeline.kick()
-
-    # -- inter-cube migration (put-then-signal; see serve/cube_proc.py) -------
-
-    def _land_payload(self, payload: dict) -> dict:
-        """Land a migration payload's data half in the host tier and return
-        the internal entry.  ``kind='pages'`` payloads degrade to ``fresh``
-        (prompt re-submission — token-identical by greedy determinism) when
-        the host tier is absent or exhausted."""
-        entry = {
-            "uid": int(payload["uid"]),
-            "prompt": np.asarray(payload["prompt"], np.int32),
-            "max_new_tokens": int(payload["max_new_tokens"]),
-            "temperature": float(payload["temperature"]),
-            "out_tokens": [int(t) for t in payload["out_tokens"]],
-            "handle": None,
-            "committed": False,
-        }
-        if payload["kind"] == "pages":
-            handle = self.cache.host_import(
-                payload["seq"], payload["state"],
-                int(payload["length"]), int(payload["n_pages"]),
-            )
-            if handle is not None:
-                entry["handle"] = handle
-                entry["pending_token"] = int(payload["pending_token"])
-            else:
-                self._c_migr_fresh.inc()
-        self._c_migr_in.inc()
-        return entry
-
-    def migrate_put(self, token, payload: dict) -> str:
-        """The *put* half of an inter-cube request migration: land the
-        payload (page rows → host tier) under ``token``, invisible to the
-        scheduler until :meth:`migrate_signal` commits it.  Returns the
-        landed kind (``'pages'`` or ``'fresh'`` after a degrade)."""
-        with self._lock:
-            old = self._migrations.pop(token, None)
-            if old is not None and old["handle"] is not None:
-                self.cache.host_free(old["handle"])
-            entry = self._land_payload(payload)
-            self._migrations[token] = entry
-        return "pages" if entry["handle"] is not None else "fresh"
-
-    def migrate_signal(self, token) -> None:
-        """The *signal* half: commit a landed migration.  The decode loop's
-        :meth:`poll_migrations` (start of every step) schedules it."""
-        with self._lock:
-            entry = self._migrations.get(token)
-            if entry is None:
-                raise KeyError(f"migrate_signal({token!r}): no landed put")
-            entry["committed"] = True
-            self._cv.notify_all()
-
-    def pending_migrations(self) -> int:
-        """Committed-but-unscheduled migrations (the worker loop's cheap
-        should-I-step signal)."""
-        with self._lock:
-            return sum(1 for m in self._migrations.values() if m["committed"])
-
-    def _schedule_entry(self, entry: dict) -> None:
-        """Turn a committed migration entry into a scheduled request at the
-        FRONT of the waiting queue (it already holds progress — same
-        starvation argument as a preemption requeue).  Under the lock."""
-        req = Request(
-            uid=entry["uid"], prompt=entry["prompt"],
-            max_new_tokens=entry["max_new_tokens"],
-            temperature=entry["temperature"],
-            out_tokens=list(entry["out_tokens"]),
-        )
-        state = RequestState(
-            req=req, resume_tokens=np.asarray(req.prompt, np.int32),
-            tracer=self.tracer, submit_ts=obs_clock.monotonic(),
-        )
-        if entry["handle"] is not None:
-            # page path: indistinguishable from a local swap-preempted
-            # request — the ordinary swapped-restore machinery (admit_next
-            # restore branch → stage_in → commit_swap_in) takes over
-            state.swapped = True
-            state.swap_handle = entry["handle"]
-            state.length = entry["handle"].length
-            state.pending_token = entry["pending_token"]
-            self._c_migr_resumed.inc()
-        elif req.out_tokens:
-            # fresh fallback with progress: the recompute-resume restart
-            # (re-prefill prompt + generated, keep sampled tokens)
-            state.resume_tokens = np.concatenate([
-                np.asarray(req.prompt, np.int32),
-                np.asarray(req.out_tokens[:-1], np.int32),
-            ])
-            state.is_resume = True
-        self.sched.waiting.insert(0, state)
-
-    @decode_loop_only
-    def poll_migrations(self) -> int:
-        """Adopt every committed migration into the scheduler (called at
-        the top of each decode step and by the cube worker loop).  Returns
-        the number scheduled."""
-        with self._lock:
-            ready = [t for t, m in self._migrations.items() if m["committed"]]
-            for t in ready:
-                self._schedule_entry(self._migrations.pop(t))
-            if ready:
-                self._cv.notify_all()
-        if ready:
-            self.pipeline.kick()
-        return len(ready)
-
-    # shadow checkpoints: standby copies of requests running elsewhere ------
-
-    def shadow_put(self, uid: int, payload: dict) -> str:
-        """Land a standby checkpoint for ``uid`` (a request running on
-        another cube).  Replaces any earlier shadow for the uid; host pages
-        of the replaced shadow are freed."""
-        uid = int(uid)
-        with self._lock:
-            old = self._shadows.pop(uid, None)
-            if old is not None and old["handle"] is not None:
-                self.cache.host_free(old["handle"])
-            entry = self._land_payload(payload)
-            self._shadows[uid] = entry
-        return "pages" if entry["handle"] is not None else "fresh"
-
-    def shadow_signal(self, uid: int) -> None:
-        with self._lock:
-            entry = self._shadows.get(int(uid))
-            if entry is None:
-                raise KeyError(f"shadow_signal({uid}): no landed put")
-            entry["committed"] = True
-
-    @decode_loop_only
-    def adopt_shadow(self, uid: int) -> bool:
-        """Promote a COMMITTED shadow into a scheduled request (its cube
-        died).  Returns False when no committed shadow exists — the caller
-        re-submits from the prompt instead."""
-        with self._lock:
-            entry = self._shadows.get(int(uid))
-            if entry is None or not entry["committed"]:
-                return False
-            self._shadows.pop(int(uid))
-            self._schedule_entry(entry)
-            self._cv.notify_all()
-        self.pipeline.kick()
-        return True
-
-    def drop_shadow(self, uid: int) -> None:
-        """Discard a shadow (its request completed) and free its pages."""
-        with self._lock:
-            entry = self._shadows.pop(int(uid), None)
-            if entry is not None and entry["handle"] is not None:
-                self.cache.host_free(entry["handle"])
-
-    def _fresh_payload(self, req, out_tokens) -> dict:
-        return {
-            "kind": "fresh", "uid": req.uid,
-            "prompt": np.asarray(req.prompt, np.int32),
-            "max_new_tokens": req.max_new_tokens,
-            "temperature": req.temperature,
-            "out_tokens": [int(t) for t in out_tokens],
-        }
-
-    def _handle_payload(self, st) -> dict:
-        seq, state, length, n_pages = self.cache.host_export(st.swap_handle)
-        return {
-            "kind": "pages", "uid": st.req.uid,
-            "prompt": np.asarray(st.req.prompt, np.int32),
-            "max_new_tokens": st.req.max_new_tokens,
-            "temperature": st.req.temperature,
-            "out_tokens": [int(t) for t in st.req.out_tokens],
-            "length": length, "n_pages": n_pages,
-            "pending_token": int(st.pending_token),
-            "seq": seq, "state": state,
-        }
-
-    @decode_loop_only
-    def checkpoint_request(self, uid: int) -> dict | None:
-        """Non-destructive migration payload for an in-flight request — the
-        shadow-checkpoint read.  Running requests are read straight off the
-        device (no preemption, no state change); swapped ones off their
-        host pages; queued ones as fresh prompts.  None when ``uid`` is not
-        in flight."""
-        with self._lock:
-            for st in self.sched.running.values():
-                if st.req.uid == uid:
-                    rows, state = self.cache.export_pages(
-                        st.pages, st.lane, st.length)
-                    return {
-                        "kind": "pages", "uid": uid,
-                        "prompt": np.asarray(st.req.prompt, np.int32),
-                        "max_new_tokens": st.req.max_new_tokens,
-                        "temperature": st.req.temperature,
-                        "out_tokens": [int(t) for t in st.req.out_tokens],
-                        "length": st.length, "n_pages": len(st.pages),
-                        "pending_token": int(st.pending_token),
-                        "seq": rows, "state": state,
-                    }
-            for st in self.sched.waiting:
-                if st.req.uid == uid:
-                    if st.swapped:
-                        return self._handle_payload(st)
-                    return self._fresh_payload(st.req, st.req.out_tokens)
-        return None
-
-    @decode_loop_only
-    def export_request(self, uid: int) -> dict | None:
-        """WITHDRAW an in-flight request and return its migration payload
-        (the router draining a straggler).  Running requests are first
-        swap-preempted so their pages land in the host tier; requests mid-
-        admission (pipeline actively computing into their private buffers)
-        are left alone — returns None, they finish where they are."""
-        with self._lock:
-            st = None
-            for cand in self.sched.running.values():
-                if cand.req.uid == uid:
-                    st = cand
-                    break
-            if st is not None:
-                self.sched.preempt(st, self.cache)
-            for cand in self.sched.waiting:
-                if cand.req.uid == uid:
-                    st = cand
-                    break
-            else:
-                return None
-            if st.swapped:
-                payload = self._handle_payload(st)
-                self.cache.host_free(st.swap_handle)
-                st.swap_handle = None
-                st.swapped = False
-            else:
-                payload = self._fresh_payload(st.req, st.req.out_tokens)
-            self.sched.waiting.remove(st)
-            self.sched.retire_uid(uid)
-            return payload
 
     # -- prefill (called by the admission pipeline, OUTSIDE the lock) ---------
 
@@ -1147,10 +899,6 @@ class ServeEngine:
         if self.pipeline.error is not None:
             err, self.pipeline.error = self.pipeline.error, None
             raise RuntimeError("admission pipeline died") from err
-        # committed inter-cube migrations enter the scheduler BEFORE the
-        # idle check — a drained engine that just received a migration must
-        # schedule it this step, not report itself done
-        self.poll_migrations()
         s, c = self.sched, self.ecfg
         with self._lock:
             idle = s.load == 0
@@ -1239,18 +987,6 @@ class ServeEngine:
         with self._lock:
             return self.sched.load
 
-    def inflight_uids(self) -> list[int]:
-        """Uids of every request currently in the engine (waiting,
-        admitting, ready, or running) — the cube worker's checkpoint set."""
-        with self._lock:
-            s = self.sched
-            return sorted(
-                {st.req.uid for st in s.waiting}
-                | {st.req.uid for st in s.admitting}
-                | {st.req.uid for st in s.ready}
-                | {st.req.uid for st in s.running.values()}
-            )
-
     def prefix_match_tokens(self, prompt) -> int:
         """Resident-prefix coverage for a prompt, in tokens — the router's
         prefix-affinity signal.  0 when prefix sharing is off."""
@@ -1316,10 +1052,6 @@ class ServeEngine:
             host_occ = self.cache.host_occupancy()
             has_host = self.cache.host is not None
             has_prefix = self.cache.prefix is not None
-            migr = {
-                "pending": len(self._migrations),
-                "shadows": len(self._shadows),
-            }
         c = snap["counters"]
         st: dict = {
             "steps": c["steps"],
@@ -1341,11 +1073,6 @@ class ServeEngine:
         }
         st["page_occupancy"] = page_occ
         st["host_page_occupancy"] = host_occ
-        migr.update({
-            k[len("migrate."):]: v for k, v in c.items()
-            if k.startswith("migrate.")
-        })
-        st["migrations"] = migr
         if has_host:
             st["host_tier"] = {
                 k[len("host."):]: v for k, v in c.items()

@@ -82,10 +82,6 @@ class HostPagePool:
         "device_gets",                      # host-blocking device→host reads
         "dirty_pages_skipped",              # clean-prefix reuse
         "exhausted_fallbacks",              # host pool couldn't cover a swap
-        # inter-cube page migration (serve/cube_proc.py put-then-signal):
-        # payloads exported from / landed into this tier
-        "migrations_out", "migrations_in",
-        "migration_pages_out", "migration_pages_in",
     )
 
     def __init__(self, device_pools, n_pages: int, page_size: int,
@@ -326,56 +322,6 @@ class HostPagePool:
 
         pools = jax.tree_util.tree_map_with_path(leaf, device_pools, staged)
         return pools, state
-
-    # -- inter-cube migration (the data half of put-then-signal) -----------
-
-    def export_handle(self, handle: SwapHandle):
-        """Pure read of a request's host-resident pages for inter-cube
-        migration: returns ``(seq_rows, state, length, n_pages)``.
-        ``seq_rows`` mirrors the buffer tree with each seq leaf cut to the
-        handle's pages in logical order (non-seq leaves stay 0-d
-        placeholders) — a copy, so the source handle stays valid until the
-        caller frees it.  No allocator or pool state changes: this is the
-        read side of a one-sided put."""
-        host_idx = np.asarray(handle.host_pages, np.int64)
-
-        def leaf(path, buf):
-            if not _is_seq(path) or host_idx.size == 0:
-                return np.zeros((), buf.dtype)
-            return np.ascontiguousarray(buf[:, host_idx])
-
-        rows = jax.tree_util.tree_map_with_path(leaf, self.buffers)
-        self._bump(migrations_out=1,
-                   migration_pages_out=len(handle.host_pages))
-        return rows, handle.state, handle.length, len(handle.host_pages)
-
-    @pool_mutator("free_list")
-    def import_pages(self, seq_rows, state, length: int, n_pages: int):
-        """Allocation half of an inter-cube migration landing (the "put"):
-        acquire ``n_pages`` host pages, write the payload rows into them,
-        and return a ``SwapHandle`` indistinguishable from a local
-        swap-out's — the ordinary swapped-restore path takes it from here.
-        Returns None (nothing held) when the pool cannot cover it; the
-        caller degrades to prompt re-submission."""
-        got = self.allocator.acquire(n_pages) if n_pages else []
-        if got is None:
-            self._bump(exhausted_fallbacks=1)
-            return None
-        host_idx = np.asarray(got, np.int64)
-
-        def copy(path, buf, rows):
-            if _is_seq(path) and host_idx.size:
-                buf[:, host_idx] = rows
-                self._bump(bytes_in=rows.nbytes)
-            return buf
-
-        jax.tree_util.tree_map_with_path(copy, self.buffers, seq_rows)
-        self._bump(migrations_in=1, migration_pages_in=n_pages)
-        return SwapHandle(
-            host_pages=list(got),
-            clean_pages=min(length // self.page_size, n_pages),
-            length=length, state=state,
-        )
 
     @pool_mutator("free_list")
     def free(self, handle: SwapHandle | None) -> None:

@@ -876,46 +876,6 @@ class PagedKVCache:
         if self.host is not None:
             self.host.free(handle)
 
-    # -- inter-cube migration (serve/cube_proc.py) -------------------------
-
-    def host_import(self, seq_rows, state, length: int, n_pages: int):
-        """Land a migration payload in the host tier: returns a
-        ``SwapHandle`` the ordinary swapped-restore path consumes, or None
-        (host tier absent/exhausted — caller degrades to prompt
-        re-submission)."""
-        if self.host is None:
-            return None
-        return self.host.import_pages(seq_rows, state, length, n_pages)
-
-    def host_export(self, handle):
-        """Export a swapped request's host pages as a migration payload
-        ``(seq_rows, state, length, n_pages)`` — pure read, handle stays
-        valid until freed."""
-        return self.host.export_handle(handle)
-
-    def export_pages(self, pages: list[int], lane, length: int):
-        """Non-destructive device→host read of a request's pages (and its
-        lane's recurrent state when running) as a migration/shadow payload
-        ``(seq_rows, state)``.  Decode-loop-only: reads the device pools."""
-        dev_idx = jnp.asarray(pages, jnp.int32)
-
-        def seq_leaf(path, pool):
-            if not _is_seq(path):
-                return np.zeros((), np.dtype(pool.dtype))
-            return np.asarray(jnp.take(pool, dev_idx, axis=1))
-
-        rows = jax.tree_util.tree_map_with_path(seq_leaf, self.pools)
-        state = None
-        if lane is not None and self.has_state_leaves():
-
-            def st_leaf(path, pool):
-                if _is_seq(path):
-                    return np.zeros((), np.dtype(pool.dtype))
-                return np.asarray(pool[:, lane:lane + 1])
-
-            state = jax.tree_util.tree_map_with_path(st_leaf, self.pools)
-        return rows, state
-
     def host_occupancy(self) -> float:
         return self.host.occupancy() if self.host is not None else 0.0
 

@@ -25,6 +25,8 @@ from repro.dist.sharding import (
 )
 from repro.models import build_model
 from repro.models.common import AxisRules, DEFAULT_RULES, PSpec
+from repro.obs import clock as obs_clock
+from repro.obs.clock import ManualClock
 
 RULES = AxisRules(DEFAULT_RULES)
 
@@ -234,3 +236,37 @@ def test_dead_host_relative_to_freshest_report():
     assert det.dead() == [2]                          # silent for 30 s
     assert det.dead(now=4.0) == []                    # injected clock wins
     assert det.stragglers() == []                     # slow ≠ dead
+
+
+def test_straggler_detector_uses_injectable_clock():
+    clk = ManualClock()
+    obs_clock.set_source(clk)
+    try:
+        det = StragglerDetector(n_hosts=3, factor=2.0, timeout=10.0)
+        # hosts 0/1 report every second; host 2 manages two reports 5s
+        # apart — a 5x step time, flagged against the 1.0s median
+        for i in range(6):
+            det.report(0, i)
+            det.report(1, i)
+            if i in (0, 5):
+                det.report(2, i)
+            clk.advance(1.0)
+        assert det.stragglers() == [2]
+        clk.advance(20.0)                 # host 2 goes silent past timeout
+        det.report(0, 6)
+        det.report(1, 6)
+        assert det.dead(now=obs_clock.monotonic()) == [2]
+        det.forget(2)
+        assert det.dead(now=obs_clock.monotonic()) == []
+        assert det.stragglers() == []     # history gone with the cube
+    finally:
+        obs_clock.reset_source()
+
+
+def test_straggler_detector_explicit_clock_override():
+    t = {"now": 100.0}
+    det = StragglerDetector(n_hosts=2, timeout=5.0, clock=lambda: t["now"])
+    det.report(0, 1)
+    t["now"] = 112.0
+    det.report(1, 1)
+    assert det.dead(now=t["now"]) == [0]

@@ -34,7 +34,6 @@ from repro.obs.export import (chrome_trace, load_chrome_trace,
                               write_chrome_trace)
 from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.trace import NULL_TRACER, PH_COUNTER, ServeTracer, Tracer
-from repro.obs.wire import unwire_snapshot, wire_snapshot
 
 
 @pytest.fixture
@@ -251,24 +250,6 @@ def test_validate_lifecycles_rejects_illegal_edges():
     assert validate_lifecycles(mid, require_done=False)
     with pytest.raises(ValueError, match="no phase"):
         validate_lifecycles(fake({}))
-
-
-def test_wire_snapshot_roundtrip_through_collectives():
-    from repro.dist.collectives import compress_tree, decompress_tree
-
-    reg = MetricsRegistry()
-    reg.inc("steps", 7)
-    reg.gauge_set("occ", 0.25)
-    reg.observe("lat", 0.5, edges=(0.1, 1.0))
-    snap = reg.snapshot()
-    snap["label"] = "host-side only"     # non-numeric leaves stay home
-    wired = wire_snapshot(snap)
-    assert "label" not in wired
-    tree, scales = compress_tree(wired, "bf16")
-    back = unwire_snapshot(decompress_tree(tree, scales, "bf16"))
-    assert back["counters"]["steps"] == 7.0
-    assert back["gauges"]["occ"]["value"] == pytest.approx(0.25)
-    assert back["histograms"]["lat"]["counts"] == [0.0, 1.0, 0.0]
 
 
 # -- sanitizer integration ---------------------------------------------------

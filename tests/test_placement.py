@@ -55,9 +55,7 @@ def test_router_places_one_engine_per_device():
         from repro.models import build_model
         from repro.serve import (CacheConfig, CubeRouter, EngineConfig,
                                  Request, ServeEngine)
-        from repro.serve.cube_proc import refuse_on_tpu
 
-        refuse_on_tpu()                      # a no-op off the TPU
         cfg = get_arch("qwen2.5-3b").reduced()
         model = build_model(cfg)
         params = model.init(jax.random.key(0))

@@ -21,6 +21,8 @@ from repro.configs import get_arch
 from repro.models import build_model
 from repro.models.common import AxisRules, DEFAULT_RULES
 from repro.serve import (
+    AdmissionConfig,
+    CacheConfig,
     CubeRouter,
     DenseSlotEngine,
     EngineConfig,
@@ -31,6 +33,7 @@ from repro.serve import (
     SchedulerConfig,
     ServeEngine,
 )
+from repro.serve import router as router_mod
 from repro.serve.paged_cache import gather_views
 
 RULES = AxisRules(DEFAULT_RULES)
@@ -656,6 +659,149 @@ def test_router_hash_and_least_loaded(served):
     assert sorted(cubes) == [0, 0, 1, 1]     # queue-depth balanced
     with pytest.raises(ValueError):
         CubeRouter(model, params, ecfg, n_cubes=1, policy="round_robin")
+
+
+def _swap_ecfg(**cache):
+    """Two lanes over 16 pages of 4 tokens: three 31-token requests on a
+    cube outgrow the pool, so each cube swap-preempts.  Inline admission
+    keeps the queue movement deterministic."""
+    kw = dict(page_size=4, n_pages=16, preempt_policy="swap",
+              swap_token_cost=0.0)
+    kw.update(cache)
+    return EngineConfig(batch_slots=2, max_len=32, cache=CacheConfig(**kw),
+                        admission=AdmissionConfig(async_prefill=False))
+
+
+def _prefixed_reqs(cfg, n, prefix_len=8, tail=3, max_new=16, uid0=0,
+                   seed=11):
+    """``n`` requests whose prompts share one ``prefix_len``-token prefix
+    (two full pages) and differ in their last ``tail`` tokens."""
+    rng = np.random.default_rng(seed)
+    prefix = rng.integers(0, cfg.vocab_size, size=(prefix_len,))
+    return [
+        Request(uid=uid0 + i,
+                prompt=np.concatenate([
+                    prefix, rng.integers(0, cfg.vocab_size, size=(tail,)),
+                ]).astype(np.int32),
+                max_new_tokens=max_new)
+        for i in range(n)
+    ]
+
+
+def _route(model, params, ecfg, reqs, policy):
+    """Serve ``reqs`` through a 2-cube router: (tokens by uid, router,
+    the cube each request was sent to)."""
+    rt = CubeRouter(model, params, ecfg, n_cubes=2, policy=policy)
+    cubes = [rt.submit(r) for r in reqs]
+    done = rt.run()
+    return {r.uid: r.out_tokens for r in done}, rt, cubes
+
+
+@pytest.mark.parametrize("arch", PAGED_FAMILIES)
+def test_router_serves_like_one_engine(arch):
+    """Two cubes under swap pressure emit, per uid, the tokens one engine
+    emits for the same workload, and both pools drain back to full."""
+    cfg, model, params = _family_model(arch)
+    ecfg = _swap_ecfg()
+    reqs = lambda: _reqs(cfg, n=6, plen=7, max_new=24, seed=7)  # noqa: E731
+    want, _ = _serve(ServeEngine, model, params, ecfg, reqs())
+    got, rt, cubes = _route(model, params, ecfg, reqs(), "least_loaded")
+    assert got == want
+    assert sorted(cubes) == [0, 0, 0, 1, 1, 1]
+    tel = rt.telemetry()
+    for i, eng in enumerate(rt.engines):
+        assert tel[f"pod{i}"]["swap_preemptions"] > 0
+        assert eng.cache.allocator.n_free == eng.cache.n_pages
+
+
+@pytest.mark.parametrize("policy", ["hash", "least_loaded", "prefix_affinity"])
+def test_router_policy_serves_like_one_engine(served, policy):
+    cfg, model, params = served
+    ecfg = _swap_ecfg(prefix_sharing=True)
+    reqs = lambda: _prefixed_reqs(cfg, n=6)  # noqa: E731
+    want, _ = _serve(ServeEngine, model, params, ecfg, reqs())
+    got, rt, _ = _route(model, params, ecfg, reqs(), policy)
+    assert got == want
+    assert rt.telemetry()["total_routed"] == 6
+
+
+def test_router_prefix_affinity_follows_resident_prefix(served):
+    cfg, model, params = served
+    rt = CubeRouter(model, params, _swap_ecfg(prefix_sharing=True),
+                    n_cubes=2, policy="prefix_affinity")
+    seed_req, follower = _prefixed_reqs(cfg, n=2)
+    rt.engines[1].submit(seed_req)        # the prefix becomes resident on 1
+    rt.run()
+    for r in _reqs(cfg, n=2, plen=7, max_new=2, seed=3):
+        rt.engines[1].submit(r)           # and cube 1 the busier one
+    assert rt.engines[1].load > rt.engines[0].load
+    assert rt.engines[1].prefix_match_tokens(follower.prompt) == 8
+    assert rt.engines[0].prefix_match_tokens(follower.prompt) == 0
+    assert rt.submit(follower) == 1
+    done = rt.run()
+    assert follower.uid in {r.uid for r in done}
+
+
+def test_router_prefix_affinity_ties_go_least_loaded(served):
+    cfg, model, params = served
+    rt = CubeRouter(model, params, _swap_ecfg(prefix_sharing=True),
+                    n_cubes=2, policy="prefix_affinity")
+    fillers = iter(_reqs(cfg, n=4, plen=7, max_new=2, seed=3))
+    fresh = _reqs(cfg, n=2, plen=7, max_new=2, seed=5)
+    # no resident match anywhere: the emptier cube wins, either way round
+    rt.engines[0].submit(next(fillers))
+    assert rt.submit(fresh[0]) == 1
+    rt.engines[1].submit(next(fillers))
+    rt.engines[1].submit(next(fillers))
+    assert rt.submit(fresh[1]) == 0
+    rt.run()
+    # the same prefix resident on both cubes: the match ties, load decides
+    a, b, probe = _prefixed_reqs(cfg, n=3, uid0=10)
+    rt.engines[0].submit(a)
+    rt.engines[1].submit(b)
+    rt.run()
+    assert [e.prefix_match_tokens(probe.prompt) for e in rt.engines] == [8, 8]
+    rt.engines[0].submit(next(fillers))
+    assert rt.submit(probe) == 1
+
+
+@pytest.mark.parametrize("case", ["wrong_count", "shared_device"])
+def test_router_rejects_bad_devices(served, monkeypatch, case):
+    cfg, model, params = served
+    d = jax.devices()[0]
+    devices = {"wrong_count": jax.devices()[:1], "shared_device": [d, d]}[case]
+
+    def no_engine(*args, **kwargs):
+        raise AssertionError("an engine was built before the device check")
+
+    monkeypatch.setattr(router_mod, "ServeEngine", no_engine)
+    with pytest.raises(ValueError):
+        CubeRouter(model, params, EngineConfig(batch_slots=1, max_len=32),
+                   n_cubes=2, devices=devices)
+
+
+def test_router_step_reports_drained(served):
+    cfg, model, params = served
+    rt = CubeRouter(model, params, _swap_ecfg(), n_cubes=2)
+    for r in _reqs(cfg, n=3, plen=7, max_new=4):
+        rt.submit(r)
+    steps = 0
+    while any(e.load for e in rt.engines):
+        assert rt.step() is True
+        steps += 1
+        assert steps < 200
+    assert rt.step() is False
+    assert sum(len(e.completed) for e in rt.engines) == 3
+
+
+def test_router_keeps_each_request_on_its_cube(served):
+    cfg, model, params = served
+    got, rt, cubes = _route(model, params, _swap_ecfg(),
+                            _reqs(cfg, n=6, plen=7, max_new=8), "hash")
+    assert sorted(got) == list(range(6))
+    for uid, cube in enumerate(cubes):
+        assert uid in {r.uid for r in rt.engines[cube].completed}
+        assert uid not in {r.uid for r in rt.engines[1 - cube].completed}
 
 
 # ---------------------------------------------------------------------------
