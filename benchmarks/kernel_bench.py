@@ -114,20 +114,23 @@ def bench_json() -> dict:
 
     # paged decode attention (XLA paged path: transient per-layer gather)
     # vs the gather-whole-view-then-attend oracle
-    qd, kpool, vpool, bt, pos = cutouts.build("attn.paged_decode")
+    qd, kpool, vpool, bt, pos, layer = cutouts.build("attn.paged_decode")
     lanes, p = bt.shape
-    _, ps, hkv, d = kpool.shape
-    impl = jax.jit(lambda *a: paged_decode_attention_xla(*a))
+    hkv, ps = kpool.shape[2:4]
+    d = qd.shape[-1]
+    impl = jax.jit(lambda *a: paged_decode_attention_xla(*a, layer))
 
     def _oracle(qq, kp, vp, table, position):
-        kd = ref.paged_gather(kp, table).reshape(lanes, p * ps, hkv, d)
-        vd = ref.paged_gather(vp, table).reshape(lanes, p * ps, hkv, d)
-        return decode_attention(qq, kd, vd, position=position)
+        def view(pool):          # (lanes, P, Hkv, PS, Dp) → (lanes, S, Hkv, D)
+            pages = ref.paged_gather(pool[layer], table)[..., :d]
+            return pages.transpose(0, 1, 3, 2, 4).reshape(lanes, p * ps,
+                                                          hkv, d)
+        return decode_attention(qq, view(kp), view(vp), position=position)
 
     oracle = jax.jit(_oracle)
     impl_us = _med_time(impl, qd, kpool, vpool, bt, pos)
     with no_tuning():
-        dflt = jax.jit(lambda *a: paged_decode_attention_xla(*a))
+        dflt = jax.jit(lambda *a: paged_decode_attention_xla(*a, layer))
         default_us = _med_time(dflt, qd, kpool, vpool, bt, pos)
     oracle_us = _med_time(oracle, qd, kpool, vpool, bt, pos)
     out["attn.paged_decode.us"] = round(impl_us, 1)

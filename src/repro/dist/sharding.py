@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.models.common import (
     AxisRules,
     DEFAULT_RULES,
+    KV_POOL_KEYS,
     PSpec,
     cache_leaf_key,
 )
@@ -224,9 +225,12 @@ _CACHE_LEAF_AXES: dict[str, tuple] = {
 
 # paged-pool logical axes, derived from the same table: a seq-carrying leaf
 # (batch, cache_seq, *tail) pools into (layers, pages, page_size, *tail) —
-# the page axis takes the sharding role, within-page seq stays local.
+# the page axis takes the sharding role, within-page seq stays local.  A
+# k/v page is stored head-major (``KV_POOL_KEYS``): (layers, pages,
+# kv_heads, page_size, head_dim).
 _PAGED_CACHE_LEAF_AXES: dict[str, tuple] = {
-    name: ("layers", "pages", None) + axes[axes.index("cache_seq") + 1:]
+    name: (("layers", "pages", "kv_heads", None, None) if name in KV_POOL_KEYS
+           else ("layers", "pages", None) + axes[axes.index("cache_seq") + 1:])
     for name, axes in _CACHE_LEAF_AXES.items()
     if "cache_seq" in axes
 }
@@ -234,7 +238,7 @@ _PAGED_CACHE_LEAF_AXES: dict[str, tuple] = {
 
 def paged_cache_axes(cfg, tree):
     """Logical-axis tree for a paged serving cache (``serve.paged_cache``
-    layout): seq leaves are page pools (layers, n_pages, page_size, *tail);
+    layout): seq leaves are page pools (layers, n_pages, *page);
     recurrent-state leaves keep the per-lane (layers, lanes, *tail) layout
     with lanes as the batch axis."""
 

@@ -243,26 +243,29 @@ def paged_block_pages(page_size: int, pages_per_lane: int) -> int:
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(
     q: jax.Array,             # (B, H, D) one decode token per lane
-    k_pool: jax.Array,        # (n_pages, PS, Hkv, D) — pool layout of the
-    v_pool: jax.Array,        #   paged serving cache
+    k_pool: jax.Array,        # (L, n_pages, Hkv, PS, D) — the paged serving
+    v_pool: jax.Array,        #   cache's stacked pools, as stored
     block_table: jax.Array,   # (B, P) int32, -1 = unallocated
     lengths: jax.Array,       # (B,) int32 valid tokens per lane
+    layer,                    # int32 scalar: the layer of the pools to read
     scale: float | None = None,
     interpret: bool | None = None,
 ):
     """Fused paged decode-attention read (GQA grouped, online softmax):
     each lane reads only the pages its length reaches, a block of
-    ``paged_block_pages`` pages at a time."""
+    ``paged_block_pages`` pages at a time, straight from ``layer`` of the
+    stacked pools.  The pools pass through untouched (no slice, transpose
+    or pad); only the query is padded, its rows to 8 and its lanes to the
+    pages' stored width Dp (``models.common.kv_lanes``)."""
     b, h, d = q.shape
-    n, ps, hkv, _ = k_pool.shape
+    hkv, ps, dp = k_pool.shape[2:]
     rep = h // hkv
     rep_p = rep + ((-rep) % 8)
-    dp = d + ((-d) % 128)
     qg = _pad_to(_pad_to(q.reshape(b, hkv, rep, d), 2, rep_p), 3, dp)
-    kp = _pad_to(k_pool.transpose(2, 0, 1, 3), 3, dp)
-    vp = _pad_to(v_pool.transpose(2, 0, 1, 3), 3, dp)
     out = _pa.paged_decode_attention(
-        qg, kp, vp, block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+        qg, k_pool, v_pool, block_table.astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         pages_per_block=paged_block_pages(ps, block_table.shape[1]),
         scale=float(scale) if scale is not None else float(d) ** -0.5,
         interpret=_interpret(interpret),

@@ -81,9 +81,10 @@ def paged_gather(
 def _paged_attn_kernel(
     bt_ref,           # (B, P) int32 scalar-prefetch block table
     len_ref,          # (B,) int32 valid tokens per lane (current token incl.)
+    layer_ref,        # (1,) int32 the layer of the stacked pools to read
     q_ref,            # (1, 1, rep, D)
-    k_hbm,            # (Hkv, n_pool, PS, D) — stays in HBM, pages DMA'd
-    v_hbm,            # (Hkv, n_pool, PS, D)
+    k_hbm,            # (L, n_pool, Hkv, PS, D) — stays in HBM, pages DMA'd
+    v_hbm,            # (L, n_pool, Hkv, PS, D)
     o_ref,            # (1, 1, rep, D)
     k_buf,            # (2, ppb, PS, D) double-buffered block of K pages
     v_buf,            # (2, ppb, PS, D)
@@ -94,6 +95,7 @@ def _paged_attn_kernel(
     scale: float,
 ):
     b, g = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     _, _, ps, d = k_buf.shape
     bk = pages_per_block * ps
     length = len_ref[b]
@@ -106,9 +108,9 @@ def _paged_attn_kernel(
         for j in range(pages_per_block):
             page = jnp.maximum(bt_ref[b, blk * pages_per_block + j], 0)
             out.append(pltpu.make_async_copy(
-                k_hbm.at[g, page], k_buf.at[slot, j], sems.at[0, slot]))
+                k_hbm.at[layer, page, g], k_buf.at[slot, j], sems.at[0, slot]))
             out.append(pltpu.make_async_copy(
-                v_hbm.at[g, page], v_buf.at[slot, j], sems.at[1, slot]))
+                v_hbm.at[layer, page, g], v_buf.at[slot, j], sems.at[1, slot]))
         return out
 
     @pl.when(n_blocks > 0)
@@ -166,10 +168,11 @@ def _paged_attn_kernel(
 
 def paged_decode_attention(
     q: jax.Array,             # (B, Hkv, rep, D) one decode token per lane
-    k_pool: jax.Array,        # (Hkv, n_pages, PS, D)
-    v_pool: jax.Array,        # (Hkv, n_pages, PS, D)
+    k_pool: jax.Array,        # (L, n_pages, Hkv, PS, D) every layer's pages
+    v_pool: jax.Array,        # (L, n_pages, Hkv, PS, D)
     block_table: jax.Array,   # (B, pages_per_lane) int32, -1 = unallocated
     lengths: jax.Array,       # (B,) int32 — tokens valid in the pages
+    layer: jax.Array,         # (1,) int32 — the layer of the pools to read
     pages_per_block: int,     # pages one loop step streams; divides P
     scale: float | None = None,
     interpret: bool = False,
@@ -177,9 +180,11 @@ def paged_decode_attention(
     """One grid step per (lane, KV head); inside it a loop over the blocks
     of ``pages_per_block`` pages the lane's length reaches, each block's
     pages copied from HBM into one half of a VMEM double buffer while the
-    other half is attended."""
+    other half is attended.  The pools are read where they lie: a page of
+    one head, ``k_pool[layer, page, head]``, is one contiguous (PS, D) copy,
+    so no layer slice or relayout of the pool is ever made."""
     b, hkv, rep, d = q.shape
-    _, _, ps, _ = k_pool.shape
+    ps = k_pool.shape[3]
     _, p = block_table.shape
     if p % pages_per_block:
         raise ValueError(
@@ -189,9 +194,10 @@ def paged_decode_attention(
         _paged_attn_kernel, n_pages=p, pages_per_block=pages_per_block,
         scale=scale,
     )
-    q_spec = pl.BlockSpec((1, 1, rep, d), lambda b_, g, bt, ln: (b_, g, 0, 0))
+    q_spec = pl.BlockSpec((1, 1, rep, d),
+                          lambda b_, g, bt, ln, lr: (b_, g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv),
         in_specs=[
             q_spec,
@@ -210,4 +216,4 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(block_table, lengths, q, k_pool, v_pool)
+    )(block_table, lengths, layer, q, k_pool, v_pool)

@@ -90,22 +90,25 @@ def paged_gather(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 
 def paged_decode_attention(
     q: jax.Array,             # (B, Hkv, rep, D) one decode token per lane
-    k_pool: jax.Array,        # (Hkv, n_pages, PS, D)
-    v_pool: jax.Array,        # (Hkv, n_pages, PS, D)
+    k_pool: jax.Array,        # (L, n_pages, Hkv, PS, Dp) stacked pools,
+    v_pool: jax.Array,        #   head dim padded to Dp >= D
     block_table: jax.Array,   # (B, P) int32, -1 = unallocated
     lengths: jax.Array,       # (B,) int32 valid tokens per lane
+    layer: int,               # the layer of the pools to read
     scale: float | None = None,
 ) -> jax.Array:
     """Gather-then-attend oracle for the fused paged decode read."""
     b, hkv, rep, d = q.shape
-    _, _, ps, _ = k_pool.shape
+    ps = k_pool.shape[3]
     p = block_table.shape[1]
     scale = scale if scale is not None else float(d) ** -0.5
     clipped = jnp.clip(block_table, 0, k_pool.shape[1] - 1)
-    k = jnp.take(k_pool, clipped, axis=1)          # (G, B, P, PS, D)
-    v = jnp.take(v_pool, clipped, axis=1)
-    k = k.transpose(1, 0, 2, 3, 4).reshape(b, hkv, p * ps, d)
-    v = v.transpose(1, 0, 2, 3, 4).reshape(b, hkv, p * ps, d)
+
+    def lane_view(pool):                           # → (B, Hkv, P*PS, D)
+        pages = jnp.take(pool[layer, ..., :d], clipped, axis=0)
+        return pages.transpose(0, 2, 1, 3, 4).reshape(b, hkv, p * ps, d)
+
+    k, v = lane_view(k_pool), lane_view(v_pool)
     s = jnp.einsum("bgrd,bgkd->bgrk", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     kpos = jnp.arange(p * ps)

@@ -75,7 +75,8 @@ def build_model(cfg: ArchConfig):
 def cache_page_specs(cfg_or_model, lanes: int, n_pages: int, page_size: int):
     """Per-layer page-pool shapes of the paged serving cache (the public
     entry the serve subsystem and sharding rules consume): every seq-dim
-    cache leaf becomes (layers, n_pages, page_size, *tail); recurrent-state
+    cache leaf becomes (layers, n_pages, *page) — a k/v page (Hkv,
+    page_size, Dp), any other (page_size, *tail); recurrent-state
     leaves keep their per-lane layout.  Accepts an ArchConfig or a built
     model."""
     model = (

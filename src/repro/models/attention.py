@@ -14,6 +14,8 @@ import jax.numpy as jnp
 
 from repro.tune.registry import dtype_code, tunable
 
+from .common import kv_page_load
+
 NEG_INF = -1e30
 
 
@@ -190,26 +192,37 @@ def decode_attention(
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
-def paged_lane_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
-    """Per-lane contiguous view of a page pool: (n_pages, PS, *t) + table
-    (B, P) → (B, P*PS, *t); -1 entries read as zeros.
+def paged_lane_view(pool: jax.Array, layer, block_table: jax.Array,
+                    head_dim: int | None = None) -> jax.Array:
+    """Per-lane contiguous view of one layer of a stacked page pool:
+    (L, n_pages, *page) + table (B, P) → (B, P*PS, *t); -1 entries read as
+    zeros.  ``head_dim`` marks a k/v pool, whose pages are stored
+    (Hkv, PS, Dp) and are read back token-major at ``head_dim``; without it
+    a page is (PS, *t).
 
-    Bit-identical to ``serve.paged_cache.gather_views`` on one layer's pool
-    slice — the decode-view oracle the paged attention paths are proven
-    against.  The view is transient inside the layer (XLA fuses it into the
-    attention contraction); nothing (B, max_len) survives the layer.
+    Bit-identical to ``serve.paged_cache.gather_views`` on that layer — the
+    decode-view oracle the paged attention paths are proven against.  The
+    layer and its pages are taken in one gather, so no copy of the layer's
+    pool is made, and the view is transient inside the layer (XLA fuses it
+    into the attention contraction); nothing (B, max_len) survives it.
     """
-    n_pages, ps = pool.shape[0], pool.shape[1]
+    n_pages = pool.shape[1]
     b, p = block_table.shape
-    view = jnp.take(pool, jnp.clip(block_table, 0, n_pages - 1), axis=0)
-    mask = (block_table >= 0).reshape((b, p) + (1,) * (pool.ndim - 1))
+    idx = jnp.clip(block_table, 0, n_pages - 1)
+    if head_dim is None:
+        view = pool[layer, idx]                                 # (B, P, *pg)
+    else:      # gather only the head dim's lanes of each (Hkv, PS, Dp) page
+        view = pool[layer, idx, ..., :head_dim]
+    mask = (block_table >= 0).reshape((b, p) + (1,) * (pool.ndim - 2))
     view = jnp.where(mask, view, jnp.zeros((), pool.dtype))
-    return view.reshape((b, p * ps) + pool.shape[2:])
+    if head_dim is not None:
+        view = kv_page_load(view, head_dim)
+    return view.reshape((b, -1) + view.shape[3:])
 
 
 def _paged_shape_class(q, k_pool, v_pool, block_table, *_a) -> str:
     b, _, h, d = q.shape
-    n_pages, ps, hkv, _ = k_pool.shape
+    n_pages, hkv, ps = k_pool.shape[1:4]
     p = block_table.shape[1]
     return (f"b{b}.h{h}.hkv{hkv}.d{d}.ps{ps}.p{p}.np{n_pages}"
             f".{dtype_code(k_pool.dtype)}")
@@ -233,10 +246,11 @@ def _paged_validate(params, q, *_a) -> bool:
 )
 def paged_decode_attention_xla(
     q: jax.Array,             # (B, 1, H, D) one decode token per lane
-    k_pool: jax.Array,        # (n_pages, PS, Hkv, D) one layer's page pool
+    k_pool: jax.Array,        # (L, n_pages, Hkv, PS, Dp) stacked k/v pools
     v_pool: jax.Array,
     block_table: jax.Array,   # (B, P) int32, -1 = unallocated
     positions: jax.Array,     # (B,) index of each lane's new token
+    layer,                    # int32 scalar: the layer of the pools to read
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -254,7 +268,7 @@ def paged_decode_attention_xla(
     lanes (the pre-tuner behavior); ``None`` resolves through the tuned
     table and falls back to 0.
     """
-    b = q.shape[0]
+    b, d = q.shape[0], q.shape[-1]
     if lane_block and 0 < lane_block < b:
         nb = b // lane_block
         qb = q.reshape((nb, lane_block) + q.shape[1:])
@@ -263,15 +277,15 @@ def paged_decode_attention_xla(
 
         def one(group):
             qq, tt, pp = group
-            kc = paged_lane_view(k_pool, tt)
-            vc = paged_lane_view(v_pool, tt)
+            kc = paged_lane_view(k_pool, layer, tt, d)
+            vc = paged_lane_view(v_pool, layer, tt, d)
             return decode_attention(qq, kc, vc, position=pp, window=window,
                                     scale=scale)
 
         out = jax.lax.map(one, (qb, tb, pb))
         return out.reshape((b,) + out.shape[2:])
-    kc = paged_lane_view(k_pool, block_table)
-    vc = paged_lane_view(v_pool, block_table)
+    kc = paged_lane_view(k_pool, layer, block_table, d)
+    vc = paged_lane_view(v_pool, layer, block_table, d)
     return decode_attention(q, kc, vc, position=positions, window=window,
                             scale=scale)
 

@@ -119,6 +119,35 @@ DEFAULT_RULES = {
 # ``dist.sharding._CACHE_LEAF_AXES``.
 SEQ_CACHE_KEYS = ("k", "v", "ck", "cv", "latent", "k_rope")
 
+# seq leaves whose pool pages are stored head-major, (Hkv, PS, Dp): one
+# head's page is one contiguous (PS, Dp) block, the unit the paged decode
+# kernel copies from the pool where it lies, and Dp is the head dim rounded
+# up to the TPU's 128 lanes (Mosaic copies no narrower page).  The other
+# seq leaves (MLA latent/k_rope) store pages token-major, (PS, *t), like
+# the dense cache.
+KV_POOL_KEYS = ("k", "v")
+
+
+def kv_lanes(head_dim: int) -> int:
+    """Stored width of a k/v pool page row: the head dim padded to 128."""
+    return head_dim + (-head_dim) % 128
+
+
+def kv_page_store(x: jax.Array) -> jax.Array:
+    """Token-major k/v pages (..., PS, Hkv, D) → the pool's stored order
+    (..., Hkv, PS, Dp)."""
+    x = jnp.swapaxes(x, -3, -2)
+    d = x.shape[-1]
+    if kv_lanes(d) == d:      # no pad op: eager callers compile one per shape
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, kv_lanes(d) - d)])
+
+
+def kv_page_load(x: jax.Array, head_dim: int) -> jax.Array:
+    """Stored k/v pages (..., Hkv, PS, Dp) → token-major (..., PS, Hkv,
+    head_dim)."""
+    return jnp.swapaxes(x[..., :head_dim], -3, -2)
+
 
 def cache_leaf_key(path) -> str | None:
     """Innermost string dict key of a tree_map_with_path leaf path — the

@@ -185,16 +185,18 @@ def mla_decode(
 
 
 def mla_decode_paged(
-    cfg, p, x, cache: dict, block_table, positions, active, rules: AxisRules,
+    cfg, p, x, cache: dict, layer, block_table, positions, active,
+    rules: AxisRules,
 ) -> tuple[jax.Array, dict]:
-    """Absorbed decode directly over latent/k_rope page pools.
+    """Absorbed decode directly over stacked latent/k_rope page pools.
 
-    cache: {"latent": (n_pages, PS, rank), "k_rope": (n_pages, PS, qr)} —
-    one layer's pool slice.  The new token's latents scatter into the lane's
-    current page (inactive / unallocated lanes drop via the above-pool
-    sentinel, exactly ``paged_cache.absorb_decode``); the attention reads a
-    transient per-layer lane view, so the engine never materializes the
-    dense (B, max_len) cache tree.  Bit-exact vs the gather path.
+    cache: {"latent": (L, n_pages, PS, rank), "k_rope": (L, n_pages, PS,
+    qr)} — every layer's pool; this call reads and writes ``layer``.  The
+    new token's latents scatter into the lane's current page in place
+    (inactive / unallocated lanes drop via the above-pool sentinel, exactly
+    ``paged_cache.absorb_decode``); the attention reads a transient
+    per-layer lane view, so the engine never materializes the dense
+    (B, max_len) cache tree.  Bit-exact vs the gather path.
 
     Always the XLA form: the absorbed score is a two-term contraction
     (q_abs·latent + q_rope·k_rope) the single-pool fused Pallas kernel does
@@ -203,7 +205,7 @@ def mla_decode_paged(
     b, s1, d = x.shape                      # s1 == 1
     h = cfg.n_heads
     vd = cfg.mla.v_head_dim
-    n_pages, ps = cache["latent"].shape[0], cache["latent"].shape[1]
+    n_pages, ps = cache["latent"].shape[1], cache["latent"].shape[2]
     positions = jnp.asarray(positions, jnp.int32)
     q_nope, q_rope = _project_q_at(cfg, p, x, positions[:, None])
     new_latent, new_krope = _latent_kv_at(cfg, p, x, positions[:, None])
@@ -212,15 +214,15 @@ def mla_decode_paged(
     )[:, 0]
     page = jnp.where(active & (page >= 0), page, n_pages)   # drop sentinel
     off = positions % ps
-    latent_pool = cache["latent"].at[page, off].set(
+    latent_pool = cache["latent"].at[layer, page, off].set(
         new_latent[:, 0].astype(cache["latent"].dtype), mode="drop"
     )
-    krope_pool = cache["k_rope"].at[page, off].set(
+    krope_pool = cache["k_rope"].at[layer, page, off].set(
         new_krope[:, 0].astype(cache["k_rope"].dtype), mode="drop"
     )
-    latent_pool = constrain(latent_pool, rules, "pages", None, None)
-    latent = paged_lane_view(latent_pool, block_table)      # (B, cap, rank)
-    k_rope = paged_lane_view(krope_pool, block_table)
+    latent_pool = constrain(latent_pool, rules, "layers", "pages", None, None)
+    latent = paged_lane_view(latent_pool, layer, block_table)  # (B, cap, rank)
+    k_rope = paged_lane_view(krope_pool, layer, block_table)
     kpos = jnp.arange(latent.shape[1], dtype=jnp.int32)
     mask = (kpos[None, :] <= positions[:, None])[:, None, None, :]
     out = _absorbed_attend(cfg, p, q_nope, q_rope, latent, k_rope, mask)

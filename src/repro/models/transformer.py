@@ -29,6 +29,7 @@ from .attention import attend, decode_attention, paged_decode_attention_xla
 from .common import (
     AxisRules,
     DEFAULT_RULES,
+    KV_POOL_KEYS,
     PSpec,
     SEQ_CACHE_KEYS,
     cache_leaf_key,
@@ -36,6 +37,8 @@ from .common import (
     activation,
     constrain,
     init_params,
+    kv_lanes,
+    kv_page_store,
     rms_norm,
     rope,
 )
@@ -130,19 +133,21 @@ def attn_decode(cfg, p, x, cache, position, rules, window=None):
     return y, {"k": kc, "v": vc}
 
 
-def attn_decode_paged(cfg, p, x, cache, block_table, positions, active, rules,
-                      window=None, impl="xla"):
-    """One-token decode directly against the page pools.
+def attn_decode_paged(cfg, p, x, cache, layer, block_table, positions,
+                      active, rules, window=None, impl="xla"):
+    """One-token decode directly against the stacked page pools.
 
-    cache: {"k"/"v": (n_pages, PS, Hkv, hd)} — one layer's pool slice.  The
-    new token's k/v scatter into the lane's current page (inactive /
-    unallocated lanes drop via the above-pool sentinel, exactly
-    ``paged_cache.absorb_decode``), then one decode query per lane attends
-    over the pages its block table names: the fused Pallas kernel on
-    ``impl='pallas'``, the bit-exact transient-gather XLA form otherwise.
-    The engine-side dense (B, max_len, ...) cache tree is never built."""
+    cache: {"k"/"v": (L, n_pages, Hkv, PS, Dp)} — every layer's pool, as
+    the serving cache stores it; this call reads and writes ``layer``.  The
+    new token's k/v scatter into the lane's current page of that layer, in
+    place (inactive / unallocated lanes drop via the above-pool sentinel,
+    exactly ``paged_cache.absorb_decode``), then one decode query per lane
+    attends over the pages its block table names: the fused Pallas kernel
+    on ``impl='pallas'``, the bit-exact transient-gather XLA form otherwise.
+    Neither copies the layer's pool, and the engine-side dense
+    (B, max_len, ...) cache tree is never built."""
     b, _, d = x.shape
-    n_pages, ps = cache["k"].shape[0], cache["k"].shape[1]
+    n_pages, ps = cache["k"].shape[1], cache["k"].shape[3]
     positions = jnp.asarray(positions, jnp.int32)
     q, k, v = _qkv(cfg, p, x)
     if cfg.rope:
@@ -153,12 +158,18 @@ def attn_decode_paged(cfg, p, x, cache, block_table, positions, active, rules,
     )[:, 0]
     page = jnp.where(active & (page >= 0), page, n_pages)   # drop sentinel
     off = positions % ps
-    kc = cache["k"].at[page, off].set(k[:, 0].astype(cache["k"].dtype),
-                                      mode="drop")
-    vc = cache["v"].at[page, off].set(v[:, 0].astype(cache["v"].dtype),
-                                      mode="drop")
-    kc = constrain(kc, rules, "pages", None, "kv_heads", None)
-    vc = constrain(vc, rules, "pages", None, "kv_heads", None)
+    heads = jnp.arange(cfg.n_kv_heads)[None, :]
+
+    def write(pool, new):
+        # each lane's token is one contiguous Dp row per head, written in
+        # place at [layer, page, head, off]
+        row = kv_page_store(new)[:, :, 0]                 # (B, Hkv, Dp)
+        return pool.at[layer, page[:, None], heads, off[:, None]].set(
+            row.astype(pool.dtype), mode="drop")
+
+    kc, vc = write(cache["k"], k), write(cache["v"], v)
+    kc = constrain(kc, rules, "layers", "pages", "kv_heads", None, None)
+    vc = constrain(vc, rules, "layers", "pages", "kv_heads", None, None)
     if impl == "pallas" and window is None:
         # the fused kernel has no sliding-window mask; windowed layers
         # (hybrid local attention) take the XLA form below
@@ -167,11 +178,11 @@ def attn_decode_paged(cfg, p, x, cache, block_table, positions, active, rules,
         lengths = jnp.where(active, positions + 1, 0)
         out = kops.paged_attention(
             q.reshape(b, cfg.n_heads, cfg.hd), kc, vc, block_table, lengths,
-            scale=cfg.attention_multiplier,
+            layer, scale=cfg.attention_multiplier,
         )[:, None]
     else:
         out = paged_decode_attention_xla(
-            q, kc, vc, block_table, positions, window=window,
+            q, kc, vc, block_table, positions, layer, window=window,
             scale=cfg.attention_multiplier,
         )
     y = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["wo"]
@@ -352,35 +363,41 @@ def layer_decode(cfg, kind, p, x, cache, position, rules):
     return _ffn(cfg, kind, p, x, rules), cache
 
 
-def layer_decode_paged(cfg, kind, p, x, cache, block_table, positions, active,
-                       rules, impl="xla"):
-    """One-token decode over one layer's *paged* cache slice: attention
-    kinds read/write the page pools through the block table; recurrent
-    kinds step their per-lane state as in ``layer_decode``, with inactive
-    lanes keeping their previous state (``absorb_decode`` semantics)."""
+def layer_decode_paged(cfg, kind, p, x, cache, layer, block_table, positions,
+                       active, rules, impl="xla"):
+    """One-token decode of ``layer`` against the segment's stacked cache
+    leaves: attention kinds read/write that layer of the page pools through
+    the block table, in place; recurrent kinds step that layer's per-lane
+    state as in ``layer_decode``, inactive lanes keeping their previous
+    state (``absorb_decode`` semantics), and write the row back in place."""
     if kind in ("dense", "moe", "attn"):
         h = _norm(cfg, p["ln1"], x)
         if cfg.mla:
             y, cache = _mla.mla_decode_paged(
-                cfg, p["attn"], h, cache, block_table, positions, active, rules
+                cfg, p["attn"], h, cache, layer, block_table, positions,
+                active, rules
             )
         else:
             window = (cfg.rglru.attn_window if kind == "attn"
                       else cfg.sliding_window)
             y, cache = attn_decode_paged(
-                cfg, p["attn"], h, cache, block_table, positions, active,
-                rules, window, impl
+                cfg, p["attn"], h, cache, layer, block_table, positions,
+                active, rules, window, impl
             )
         x = _residual(cfg, x, y)
         x = _ffn(cfg, kind, p, x, rules)
     elif kind in ("ssm", "rec"):
-        x, new_cache = layer_decode(cfg, kind, p, x, cache, positions, rules)
+        old = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                   keepdims=False), cache)
+        x, new = layer_decode(cfg, kind, p, x, old, positions, rules)
 
-        def _keep(old, new):
+        def _keep(full, old, new):
             sel = active.reshape((active.shape[0],) + (1,) * (old.ndim - 1))
-            return jnp.where(sel, new.astype(old.dtype), old)
+            row = jnp.where(sel, new.astype(old.dtype), old)
+            return jax.lax.dynamic_update_index_in_dim(full, row, layer, 0)
 
-        cache = jax.tree.map(_keep, cache, new_cache)
+        cache = jax.tree.map(_keep, cache, old, new)
     else:
         raise ValueError(kind)
     return x, cache
@@ -426,10 +443,6 @@ def layer_cache_spec(cfg, kind, batch, max_len):
         # window-sized ring buffer is a recorded §Perf optimization)
         return attn_cache_spec(cfg, batch, max_len)
     raise ValueError(kind)
-
-
-# layer kinds whose cache is fixed-size per-lane state (no sequence dim)
-_STATE_KINDS = ("ssm", "rec")
 
 
 # ---------------------------------------------------------------------------
@@ -716,12 +729,15 @@ class DecoderLM:
         positions (B,), active (B,) → (logits (B,1,V), pools).
 
         The paged counterpart of ``decode_step``: seq-cache leaves are page
-        *pools* (reps, n_pages, PS, *t) read/written through the block table
+        *pools* (reps, n_pages, *page) read/written through the block table
         inside each layer (``attn_decode_paged`` / ``mla_decode_paged``), so
         the engine never gathers a dense (B, max_len, ...) cache tree.
-        Recurrent-state leaves keep the per-lane layout and step in place
-        in the scan's carry (inactive lanes keep their state).  Stacked
-        decode layout only."""
+        Every leaf, pools and per-lane recurrent state alike, rides the
+        layer scan's carry whole: layer ``r`` writes its token (or state
+        row) into the stacked buffer in place and the attention reads the
+        stacked pool at ``r``, so no layer's pool is sliced out, and none is
+        written to a second buffer (as ``xs -> ys`` would).  Stacked decode
+        layout only."""
         cfg = self.cfg
         if cfg.decode_unroll_layers:
             raise NotImplementedError("paged decode needs the stacked layout")
@@ -731,56 +747,31 @@ class DecoderLM:
         block_tables = jnp.asarray(block_tables, jnp.int32)
         new_caches = []
         for si, (pattern, reps) in enumerate(self.segments):
-            def body(h, xs, _pattern=pattern):
-                pslice, cs = xs
-                new_cs = {}
+            def body(h, cs, pslice, r, _pattern=pattern):
+                cs = dict(cs)
                 for i, kind in enumerate(_pattern):
                     key = f"s{i}_{kind}"
-                    h, c = layer_decode_paged(
-                        cfg, kind, pslice[key], h, cs[key], block_tables,
+                    h, cs[key] = layer_decode_paged(
+                        cfg, kind, pslice[key], h, cs[key], r, block_tables,
                         positions, active, rules, attn_impl
                     )
-                    new_cs[key] = c
-                return h, new_cs
+                return h, cs
 
-            carried = [f"s{i}_{k}" for i, k in enumerate(pattern)
-                       if k in _STATE_KINDS]
             if cfg.scan_layers and reps > 1:
-                # per-lane recurrent state rides the scan carry, so each
-                # layer's row is updated in place (a while loop's carry
-                # aliases its buffer); as xs -> ys the whole state would be
-                # written to a second buffer.  Page pools stay xs -> ys.
-                def carry_body(carry, xs, _body=body, _keys=carried):
-                    h, st = carry
-                    pslice, cs, r = xs
-                    cs = dict(cs)
-                    for k in _keys:
-                        cs[k] = jax.tree.map(
-                            lambda a: jax.lax.dynamic_index_in_dim(
-                                a, r, 0, keepdims=False), st[k])
-                    h, new_cs = _body(h, (pslice, cs))
-                    st = {k: jax.tree.map(
-                        lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                            a, u.astype(a.dtype), r, 0), st[k], new_cs.pop(k))
-                        for k in _keys}
-                    return (h, st), new_cs
+                def carry_body(carry, xs, _body=body):
+                    h, cs = carry
+                    pslice, r = xs
+                    return _body(h, cs, pslice, r), None
 
-                seg_pools = pools[si]
-                (x, st), new_cache = jax.lax.scan(
-                    carry_body, (x, {k: seg_pools[k] for k in carried}),
-                    (params[f"seg{si}"],
-                     {k: v for k, v in seg_pools.items() if k not in carried},
-                     jnp.arange(reps)),
+                (x, new_cache), _ = jax.lax.scan(
+                    carry_body, (x, pools[si]),
+                    (params[f"seg{si}"], jnp.arange(reps)),
                 )
-                new_cache = {**new_cache, **st}
             else:
-                slices = []
+                new_cache = pools[si]
                 for r in range(reps):
                     pslice = jax.tree.map(lambda a, r=r: a[r], params[f"seg{si}"])
-                    cslice = jax.tree.map(lambda a, r=r: a[r], pools[si])
-                    x, c = body(x, (pslice, cslice))
-                    slices.append(c)
-                new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *slices)
+                    x, new_cache = body(x, new_cache, pslice, r)
             new_caches.append(new_cache)
         logits = self._head(params, x, rules)
         return logits, new_caches
@@ -854,9 +845,11 @@ class DecoderLM:
 
         Same pytree structure as ``cache_specs(lanes, page_size)``, but every
         sequence-carrying leaf (``SEQ_CACHE_KEYS``) swaps its lane dim for a
-        page-pool dim: (reps, n_pages, page_size, *tail).  Leaves without a
-        seq dim (recurrent state) keep the per-lane layout — they are the
-        "one page per request" state the scheduler never splits.
+        page-pool dim: (reps, n_pages, *page).  A k/v page is stored
+        head-major, (Hkv, page_size, Dp) (``KV_POOL_KEYS``: the layout the
+        paged kernel reads); other pages are (page_size, *tail).  Leaves
+        without a seq dim (recurrent state) keep the per-lane layout — they
+        are the "one page per request" state the scheduler never splits.
         """
         specs = self.cache_specs(lanes, page_size)
 
@@ -865,8 +858,12 @@ class DecoderLM:
             if name not in SEQ_CACHE_KEYS:
                 return s
             bdim = seq_leaf_batch_dim(name, len(s.shape))
-            shape = s.shape[:bdim] + (n_pages,) + s.shape[bdim + 1:]
-            return jax.ShapeDtypeStruct(shape, s.dtype)
+            page = s.shape[bdim + 1:]
+            if name in KV_POOL_KEYS:
+                ps, hkv, d = page
+                page = (hkv, ps, kv_lanes(d))
+            return jax.ShapeDtypeStruct(s.shape[:bdim] + (n_pages,) + page,
+                                        s.dtype)
 
         return jax.tree_util.tree_map_with_path(leaf, specs)
 

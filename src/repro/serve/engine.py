@@ -389,7 +389,8 @@ class ServeEngine:
         if self.ecfg.decode_path == "gather":
             # fallback oracle: materialize the dense per-lane views, decode,
             # scatter the written column back into the pools
-            views = gather_views(pools, bt, impl=self.ecfg.gather_impl)
+            views = gather_views(pools, bt, self.cfg.hd,
+                                 impl=self.ecfg.gather_impl)
             logits, new_views = self.model.decode_step(
                 params, views, tokens, positions, self.rules
             )

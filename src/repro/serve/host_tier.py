@@ -10,8 +10,9 @@ pools, so the scheduler can swap a victim's pages out to host DRAM and
 restore them on resume without re-running prefill.
 
 Layout mirrors ``paged_cache``: every seq-carrying leaf
-``(layers, n_pages, PS, *tail)`` gets a host twin
-``(layers, n_host_pages, PS, *tail)``; recurrent-state leaves (SSD state,
+``(layers, n_pages, *page)`` gets a host twin
+``(layers, n_host_pages, *page)``, whole pages copied in their stored
+order; recurrent-state leaves (SSD state,
 RG-LRU h, conv rings) have no pages — a swap captures the victim lane's
 state rows wholesale into the request's ``SwapHandle`` (they mutate every
 decode step, so they are always dirty).

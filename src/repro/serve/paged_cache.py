@@ -10,7 +10,12 @@ decode step streams each lane's pages through the compute.
 Layout (stacked decode layout, ``decode_unroll_layers=False``):
 
 * seq-carrying leaves (``SEQ_CACHE_KEYS``: attention k/v, MLA latent/k_rope)
-  become pools ``(layers, n_pages, page_size, *tail)`` shared by all lanes;
+  become pools ``(layers, n_pages, *page)`` shared by all lanes.  A k/v page
+  is stored head-major, ``(Hkv, page_size, Dp)`` (``KV_POOL_KEYS``), the
+  layout the paged decode kernel reads in place; an MLA page is
+  ``(page_size, *tail)``.  Only the sites that fill or read a page's inside
+  (``write_prefill``, ``seed_prefix``, ``gather_views``, ``absorb_decode``)
+  know the order; whole-page copies (swap, fork, host tier) do not;
 * recurrent-state leaves (SSD state, RG-LRU h, conv rings) keep the per-lane
   ``(layers, lanes, *tail)`` layout — fixed-size state is its own "page".
 
@@ -29,11 +34,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.ownership import admission_api, pool_mutator
-from repro.models.common import SEQ_CACHE_KEYS, cache_leaf_key
+from repro.models.common import (
+    KV_POOL_KEYS,
+    SEQ_CACHE_KEYS,
+    cache_leaf_key,
+    kv_page_load,
+    kv_page_store,
+)
 
 
 def _is_seq(path) -> bool:
     return cache_leaf_key(path) in SEQ_CACHE_KEYS
+
+
+def _is_kv(path) -> bool:
+    return cache_leaf_key(path) in KV_POOL_KEYS
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -559,33 +574,37 @@ class PrefixIndex:
 # ---------------------------------------------------------------------------
 
 
-def gather_views(pools, block_tables: jax.Array, impl: str = "xla"):
+def gather_views(pools, block_tables: jax.Array, head_dim: int,
+                 impl: str = "xla"):
     """Materialize per-lane contiguous views from the page pools.
 
-    seq leaves: (layers, n_pages, PS, *t) + table (lanes, P) →
-    (layers, lanes, P*PS, *t); unallocated (-1) pages read as zeros so a
-    fresh lane's view is bit-identical to the dense engine's zero-init
-    cache.  State leaves pass through unchanged.
+    seq leaves: (layers, n_pages, *page) + table (lanes, P) →
+    (layers, lanes, P*PS, *t), k/v pages read back token-major at
+    ``head_dim``; unallocated (-1) pages read as zeros so a fresh lane's
+    view is bit-identical to the dense engine's zero-init cache.  State
+    leaves pass through unchanged.
     """
 
     def leaf(path, x):
         if not _is_seq(path):
             return x
-        reps, n, ps = x.shape[0], x.shape[1], x.shape[2]
+        reps, n = x.shape[0], x.shape[1]
         lanes, p = block_tables.shape
         if impl == "pallas":
             from repro.kernels import ops as kops
 
-            # (n, layers, PS, *t) page rows → (lanes, P, layers, PS, *t)
+            # (n, layers, *page) page rows → (lanes, P, layers, *page)
             got = kops.paged_gather(jnp.moveaxis(x, 0, 1), block_tables)
-            view = jnp.moveaxis(got, 2, 0)            # (layers, lanes, P, PS, *t)
+            view = jnp.moveaxis(got, 2, 0)            # (layers, lanes, P, *page)
         else:
             view = jnp.take(x, jnp.clip(block_tables, 0, n - 1), axis=1)
             mask = (block_tables >= 0).reshape(
                 (1, lanes, p) + (1,) * (view.ndim - 3)
             )
             view = jnp.where(mask, view, jnp.zeros((), x.dtype))
-        return view.reshape((reps, lanes, p * ps) + x.shape[3:])
+        if _is_kv(path):
+            view = kv_page_load(view, head_dim)
+        return view.reshape((reps, lanes, -1) + view.shape[4:])
 
     return jax.tree_util.tree_map_with_path(leaf, pools)
 
@@ -613,6 +632,12 @@ def absorb_decode(pools, new_views, block_tables, positions, active,
             # last physical page), so the sentinel is n_pages
             page = jnp.where(active & (page >= 0), page, pool.shape[1])
             off = positions % page_size
+            if _is_kv(path):
+                # each lane's token as a one-token page (layers, Hkv, Dp);
+                # [:, page, :, off] indexes (lanes, layers, Hkv, Dp)
+                tok = kv_page_store(col[:, :, None])[:, :, :, 0]
+                return pool.at[:, page, :, off].set(
+                    jnp.moveaxis(tok, 1, 0).astype(pool.dtype), mode="drop")
             return pool.at[:, page, off].set(col.astype(pool.dtype),
                                              mode="drop")
         keep = active.reshape((1, lanes) + (1,) * (pool.ndim - 2))
@@ -774,6 +799,8 @@ class PagedKVCache:
                     pad[2] = (0, cap - s)
                     pc = jnp.pad(pc, pad)
                 paged = pc.reshape((reps, len(dst), ps) + pc.shape[3:])
+                if _is_kv(path):
+                    paged = kv_page_store(paged)
                 return pool.at[:, pages_arr].set(paged.astype(pool.dtype))
             return pool
 
@@ -903,7 +930,9 @@ class PagedKVCache:
         def leaf(path, pc, pool):
             if not _is_seq(path):
                 return pc
-            take = jnp.take(pool, idx, axis=1)   # (layers, P, ps, *t)
+            take = jnp.take(pool, idx, axis=1)   # (layers, P, *page)
+            if _is_kv(path):
+                take = kv_page_load(take, pc.shape[-1])
             flat = take.reshape(
                 (take.shape[0], 1, span) + take.shape[3:]
             )

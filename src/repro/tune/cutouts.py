@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import SSMConfig
+from repro.models.common import kv_lanes
 
 
 class SsdBenchCfg:
@@ -49,15 +50,19 @@ def _flash_build(rng: np.random.Generator) -> tuple:
 
 
 def _paged_build(rng: np.random.Generator) -> tuple:
+    # one layer of stacked k/v pools as the serving cache stores them:
+    # (1, n_pages, Hkv, PS, Dp), head dim 64 padded to 128 lanes
     n_pages, ps, hkv, lanes, p, h, d = 128, 16, 2, 8, 16, 8, 64
-    kpool = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
-    vpool = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
+    shape = (1, n_pages, hkv, ps, kv_lanes(d))
+    pad = np.arange(kv_lanes(d)) < d
+    kpool = jnp.asarray(rng.normal(size=shape) * pad, jnp.float32)
+    vpool = jnp.asarray(rng.normal(size=shape) * pad, jnp.float32)
     bt = jnp.asarray(
         rng.permutation(n_pages)[: lanes * p].reshape(lanes, p), jnp.int32
     )
     pos = jnp.asarray(rng.integers(1, p * ps - 1, size=(lanes,)), jnp.int32)
     qd = jnp.asarray(rng.normal(size=(lanes, 1, h, d)), jnp.float32)
-    return (qd, kpool, vpool, bt, pos)
+    return (qd, kpool, vpool, bt, pos, 0)
 
 
 def _ssd_args(rng: np.random.Generator, cfg, hs: int, ps_: int, ns: int,

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.models.common import kv_lanes
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -157,23 +158,37 @@ _RAGGED_CASES = [
 ]
 
 
+def _pools(rng, n, g, ps, d, dt, layers=1):
+    """Stacked k/v pools as the serving cache stores them, (layers, n,
+    Hkv, PS, Dp); the lanes past the head dim hold noise, which no read may
+    let through."""
+    shape = (layers, n, g, ps, kv_lanes(d))
+    return (jnp.asarray(rng.normal(size=shape), dt),
+            jnp.asarray(rng.normal(size=shape), dt))
+
+
+def _paged_oracle(q, kpool, vpool, table, lengths, layer=0):
+    b, h, d = q.shape
+    g = kpool.shape[2]
+    return ref.paged_decode_attention(
+        q.reshape(b, g, h // g, d), kpool, vpool,
+        jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32), layer,
+    ).reshape(b, h, d)
+
+
 @pytest.mark.parametrize("table,lengths", _RAGGED_CASES)
 @pytest.mark.parametrize("dt", [F32, BF16])
 def test_paged_decode_attention_ragged(rng, table, lengths, dt):
     """Fused kernel == gather-then-attend oracle on ragged block tables
     (partial last page, single page, max pages, empty lanes)."""
     n, ps, g, d, h = 12, 16, 2, 32, 4
-    kpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), dt)
-    vpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), dt)
+    kpool, vpool = _pools(rng, n, g, ps, d, dt)
     bt = jnp.asarray(table, jnp.int32)
     ln = jnp.asarray(lengths, jnp.int32)
     b = bt.shape[0]
     q = jnp.asarray(rng.normal(size=(b, h, d)), dt)
-    got = ops.paged_attention(q, kpool, vpool, bt, ln)
-    want = ref.paged_decode_attention(
-        q.reshape(b, g, h // g, d), kpool.transpose(2, 0, 1, 3),
-        vpool.transpose(2, 0, 1, 3), bt, ln,
-    ).reshape(b, h, d)
+    got = ops.paged_attention(q, kpool, vpool, bt, ln, 0)
+    want = _paged_oracle(q, kpool, vpool, bt, ln)
     # fully-masked lanes (length 0) are don't-care outputs: the engine only
     # reads active lanes — compare where at least one key is visible
     visible = np.asarray(ln) > 0
@@ -188,28 +203,17 @@ def test_paged_decode_attention_ragged(rng, table, lengths, dt):
 _BLOCK_PS, _BLOCK_P = 16, 24
 
 
-def _block_lanes(rng, lengths, h, g, d, dt, n_pool):
+def _block_lanes(rng, lengths, h, g, d, dt, n_pool, layers=1):
     """Pools, query and a block table over the pool's first lanes x P pages
     whose entries past the pages a lane's length needs are -1 (as the
     serving cache leaves them)."""
     b = len(lengths)
-    kpool = jnp.asarray(rng.normal(size=(n_pool, _BLOCK_PS, g, d)), dt)
-    vpool = jnp.asarray(rng.normal(size=(n_pool, _BLOCK_PS, g, d)), dt)
+    kpool, vpool = _pools(rng, n_pool, g, _BLOCK_PS, d, dt, layers)
     q = jnp.asarray(rng.normal(size=(b, h, d)), dt)
     need = -(-np.asarray(lengths) // _BLOCK_PS)
     pages = rng.permutation(b * _BLOCK_P).reshape(b, _BLOCK_P)
     table = np.where(np.arange(_BLOCK_P)[None] < need[:, None], pages, -1)
     return q, kpool, vpool, table.astype(np.int32)
-
-
-def _paged_oracle(q, kpool, vpool, table, lengths):
-    b, h, d = q.shape
-    g = kpool.shape[2]
-    return ref.paged_decode_attention(
-        q.reshape(b, g, h // g, d), kpool.transpose(2, 0, 1, 3),
-        vpool.transpose(2, 0, 1, 3), jnp.asarray(table, jnp.int32),
-        jnp.asarray(lengths, jnp.int32),
-    ).reshape(b, h, d)
 
 
 @pytest.mark.parametrize("lengths", [
@@ -219,6 +223,7 @@ def _paged_oracle(q, kpool, vpool, table, lengths):
 ])
 @pytest.mark.parametrize("h,g,d", [
     pytest.param(16, 2, 128, id="qwen-h16-hkv2-d128"),
+    pytest.param(32, 8, 64, id="granite-h32-hkv8-d64"),
     pytest.param(4, 2, 32, id="h4-hkv2-d32"),
 ])
 @pytest.mark.parametrize("dt", [F32, BF16])
@@ -229,10 +234,34 @@ def test_paged_decode_attention_multi_block(rng, lengths, h, g, d, dt):
     q, kpool, vpool, table = _block_lanes(rng, lengths, h, g, d, dt,
                                           n_pool=len(lengths) * _BLOCK_P)
     got = ops.paged_attention(q, kpool, vpool, jnp.asarray(table),
-                              jnp.asarray(lengths, jnp.int32))
+                              jnp.asarray(lengths, jnp.int32), 0)
     want = _paged_oracle(q, kpool, vpool, table, lengths)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dt))
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+@pytest.mark.parametrize("h,g,d", [
+    pytest.param(16, 2, 128, id="qwen-h16-hkv2-d128"),
+    pytest.param(32, 8, 64, id="granite-h32-hkv8-d64"),
+])
+def test_paged_decode_attention_reads_the_named_layer(rng, layer, h, g, d):
+    """Stacked pools whose layers hold different data: the kernel reads
+    ``layer`` and no other — equal to the oracle at that layer and to the
+    kernel over that layer alone, unequal to the oracle at layer 0."""
+    lengths = [0, 16, 129, 300]
+    q, kpool, vpool, table = _block_lanes(rng, lengths, h, g, d, F32,
+                                          n_pool=len(lengths) * _BLOCK_P,
+                                          layers=3)
+    bt, ln = jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
+    got = np.asarray(ops.paged_attention(q, kpool, vpool, bt, ln, layer))
+    want = _paged_oracle(q, kpool, vpool, table, lengths, layer)
+    np.testing.assert_allclose(got, np.asarray(want), **_tol(F32))
+    alone = ops.paged_attention(q, kpool[layer:layer + 1],
+                                vpool[layer:layer + 1], bt, ln, 0)
+    assert np.array_equal(got, np.asarray(alone))
+    other = np.asarray(_paged_oracle(q, kpool, vpool, table, lengths, 0))
+    assert not np.allclose(got[1:], other[1:], atol=1e-2)
 
 
 @pytest.mark.parametrize("dt", [F32, BF16])
@@ -246,12 +275,12 @@ def test_paged_decode_attention_never_reads_past_the_length(rng, dt):
     q, kpool, vpool, table = _block_lanes(rng, lengths, h, g, d, dt,
                                           n_pool=b * _BLOCK_P + 1)
     nan_page = b * _BLOCK_P
-    kpool = kpool.at[nan_page].set(jnp.nan)
-    vpool = vpool.at[nan_page].set(jnp.nan)
+    kpool = kpool.at[:, nan_page].set(jnp.nan)
+    vpool = vpool.at[:, nan_page].set(jnp.nan)
     poisoned = np.where(table >= 0, table, nan_page).astype(np.int32)
     got = np.asarray(ops.paged_attention(
         q, kpool, vpool, jnp.asarray(poisoned),
-        jnp.asarray(lengths, jnp.int32)), np.float32)
+        jnp.asarray(lengths, jnp.int32), 0), np.float32)
     want = _paged_oracle(q, kpool, vpool, table, lengths)
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, np.asarray(want, np.float32), **_tol(dt))
@@ -268,19 +297,20 @@ def test_paged_block_pages(page_size, pages, want):
 
 def test_paged_decode_attention_matches_model_xla_path(rng):
     """The fused kernel and the model layer's XLA paged path agree on the
-    same pools/table/positions (positions = lengths - 1)."""
+    same stacked pools, layer, table and positions (positions = lengths
+    - 1)."""
     from repro.models.attention import paged_decode_attention_xla
 
     n, ps, g, d, h = 12, 16, 2, 32, 4
-    kpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), F32)
-    vpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), F32)
+    kpool, vpool = _pools(rng, n, g, ps, d, F32, layers=2)
+    layer = 1
     bt = jnp.asarray([[0, 3, -1, -1], [5, 2, 7, -1], [1, -1, -1, -1]],
                      jnp.int32)
     lengths = jnp.asarray([20, 45, 9], jnp.int32)
     q = jnp.asarray(rng.normal(size=(3, 1, h, d)), F32)
-    got = ops.paged_attention(q[:, 0], kpool, vpool, bt, lengths)
-    # model-layer pools are (n_pages, PS, Hkv, D) — same layout
-    want = paged_decode_attention_xla(q, kpool, vpool, bt, lengths - 1)
+    got = ops.paged_attention(q[:, 0], kpool, vpool, bt, lengths, layer)
+    want = paged_decode_attention_xla(q, kpool, vpool, bt, lengths - 1,
+                                      layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
                                rtol=1e-5, atol=1e-5)
 

@@ -19,7 +19,12 @@ import pytest
 
 from repro.configs import get_arch
 from repro.models import build_model
-from repro.models.common import AxisRules, DEFAULT_RULES
+from repro.models.common import (
+    AxisRules,
+    DEFAULT_RULES,
+    kv_lanes,
+    kv_page_store,
+)
 from repro.serve import (
     AdmissionConfig,
     CacheConfig,
@@ -106,7 +111,8 @@ def test_absorb_decode_inactive_lane_writes_nothing():
     applies and would corrupt the last physical page)."""
     from repro.serve.paged_cache import absorb_decode
 
-    pool = {"k": jnp.zeros((1, 4, 2, 1, 1), jnp.float32)}   # 4 pages of 2
+    # 4 pages of 2 tokens of one KV head, stored (Hkv, PS, Dp)
+    pool = {"k": jnp.zeros((1, 4, 1, 2, kv_lanes(1)), jnp.float32)}
     view = {"k": jnp.full((1, 2, 4, 1, 1), -5.0, jnp.float32)}
     bt = jnp.asarray([[0, 1], [-1, -1]], jnp.int32)
     out = absorb_decode(
@@ -114,8 +120,8 @@ def test_absorb_decode_inactive_lane_writes_nothing():
         active=jnp.asarray([True, False]), page_size=2,
     )
     got = np.array(out["k"])
-    assert got[0, 0, 1, 0, 0] == -5.0          # active lane 0 wrote page 0
-    got[0, 0, 1, 0, 0] = 0.0
+    assert got[0, 0, 0, 1, 0] == -5.0          # active lane 0 wrote page 0
+    got[0, 0, 0, 1, 0] = 0.0
     assert np.all(got == 0.0)                  # inactive lane wrote nowhere
 
 
@@ -160,7 +166,8 @@ def test_gather_matches_dense_cache_and_logits_bitexact(served):
         paged.write_prefill(pages, pc, lane=slot)
         paged.assign_lane(slot, pages)
 
-    view = gather_views(paged.pools, jnp.asarray(paged.block_tables))
+    view = gather_views(paged.pools, jnp.asarray(paged.block_tables),
+                        cfg.hd)
     for dv, pv in zip(jax.tree.leaves(dense), jax.tree.leaves(view)):
         assert dv.shape == pv.shape
         assert np.array_equal(np.asarray(dv), np.asarray(pv))
@@ -176,6 +183,29 @@ def test_gather_matches_dense_cache_and_logits_bitexact(served):
     lv, _ = model.decode_step(params, view, toks,
                               jnp.asarray([5, 5], jnp.int32), RULES)
     assert np.array_equal(np.asarray(ld), np.asarray(lv))
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "deepseek-v3-671b"])
+def test_seed_prefix_reads_back_what_write_prefill_stored(arch):
+    """A prefill written into pages (k/v stored head-major and padded to
+    128 lanes; MLA latents token-major) and seeded back into a fresh
+    private tree reads the same rows, bit for bit, over the seeded pages."""
+    cfg, model, params = _family_model(arch)
+    ps, n_seed = 4, 2
+    cache = PagedKVCache(model, lanes=1, n_pages=8, page_size=ps,
+                         max_len=32)
+    prompt = np.asarray([5, 9, 2, 7, 11, 3, 1, 4, 8, 6], np.int32)
+    _, pc = model.prefill(params, jnp.asarray(prompt)[None], RULES)
+    pages = [6, 2, 5]
+    cache.write_prefill(pages, pc, lane=0)
+    fresh = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                         model.cache_specs(1, 32))
+    seeded = cache.seed_prefix(fresh, pages[:n_seed])
+    span = n_seed * ps
+    for got, want in zip(jax.tree.leaves(seeded), jax.tree.leaves(pc)):
+        assert np.array_equal(np.asarray(got)[:, :, :span],
+                              np.asarray(want)[:, :, :span])
+        assert not np.asarray(got)[:, :, span:].any()
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +312,7 @@ def test_decode_step_paged_bitexact_vs_gather(arch):
     positions = jnp.asarray([5, 3, 0], jnp.int32)
     active = jnp.asarray([True, True, False])     # lane 2 is idle
 
-    views = gather_views(paged.pools, bt)
+    views = gather_views(paged.pools, bt, cfg.hd)
     lg, new_views = model.decode_step(params, views, toks, positions, RULES)
     pools_g = absorb_decode(paged.pools, new_views, bt, positions, active, ps)
 
@@ -313,6 +343,47 @@ def test_paged_decode_path_tokens_match_gather(arch):
                                    decode_path="paged"), reqs())
     assert want == got
     assert eng.cache.allocator.n_free == eng.cache.n_pages
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "granite-4.0-h-micro"])
+def test_paged_matches_gather_after_prefix_seed_and_fork(arch):
+    """The paged path == the gather oracle == serving without prefix
+    sharing, token for token, where pages were filled by chunked prefill,
+    seeded from a shared prefix (a partial match: attention-only families)
+    and forked copy-on-write (replays of a prompt whose last page is
+    shared): every site that writes or reads a page's inside in the pool's
+    stored order.  Admission runs inline: on
+    the worker thread a seed can read a pool buffer the decode step has
+    just donated (a race of the async pipeline, not of the page layout)."""
+    cfg, model, params = _family_model(arch)
+    rng = np.random.default_rng(11)
+    a = rng.integers(0, cfg.vocab_size, size=(10,)).astype(np.int32)
+    b = np.concatenate([a[:8], rng.integers(0, cfg.vocab_size, size=(5,))
+                        ]).astype(np.int32)
+    rounds = [[a], [a, b], [a]]
+
+    def serve(path, prefix=True):
+        eng = ServeEngine(model, params, EngineConfig(
+            batch_slots=2, max_len=64,
+            cache=CacheConfig(page_size=4, n_pages=32, prefix_sharing=prefix,
+                              decode_path=path),
+            admission=AdmissionConfig(prefill_chunk=4, async_prefill=False)),
+            RULES)
+        out, uid = {}, 0
+        for prompts in rounds:
+            for p in prompts:
+                eng.submit(Request(uid=uid, prompt=p, max_new_tokens=6))
+                uid += 1
+            out.update({r.uid: list(r.out_tokens) for r in eng.run()})
+        return out, eng.telemetry().get("prefix")
+
+    unshared, _ = serve("gather", prefix=False)
+    want, _ = serve("gather")
+    got, tel = serve("paged")
+    assert unshared == want == got
+    assert tel["forks"] >= 2                 # each replay forked its tail
+    if arch == "qwen2.5-3b":                 # b seeded from a's 8 tokens
+        assert tel["hit_tokens"] >= 2 * len(a) + 8
 
 
 @pytest.mark.parametrize("arch",
@@ -815,8 +886,11 @@ def test_paged_kernels_match_ref_and_decode_attention():
 
     rng = np.random.default_rng(0)
     n, ps, g, d, b, p, h = 12, 16, 2, 32, 3, 4, 4
+    # token-major pages (n, PS, Hkv, D), and the same pages as the serving
+    # cache stores them: one layer of (n, Hkv, PS, Dp)
     kpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), jnp.float32)
     vpool = jnp.asarray(rng.normal(size=(n, ps, g, d)), jnp.float32)
+    kstore, vstore = kv_page_store(kpool)[None], kv_page_store(vpool)[None]
     bt = jnp.asarray([[0, 3, -1, -1], [5, 2, 7, -1], [1, -1, -1, -1]],
                      jnp.int32)
     lengths = jnp.asarray([20, 45, 9], jnp.int32)
@@ -826,10 +900,9 @@ def test_paged_kernels_match_ref_and_decode_attention():
     assert np.array_equal(np.asarray(got), np.asarray(want))   # pure copy
 
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    got = ops.paged_attention(q, kpool, vpool, bt, lengths)
+    got = ops.paged_attention(q, kstore, vstore, bt, lengths, 0)
     want = ref.paged_decode_attention(
-        q.reshape(b, g, h // g, d), kpool.transpose(2, 0, 1, 3),
-        vpool.transpose(2, 0, 1, 3), bt, lengths,
+        q.reshape(b, g, h // g, d), kstore, vstore, bt, lengths, 0,
     ).reshape(b, h, d)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -881,8 +954,10 @@ def test_model_cache_page_specs_shapes(served):
     model2 = build_model(dataclasses.replace(cfg, decode_unroll_layers=False))
     specs = model2.cache_page_specs(lanes=3, n_pages=10, page_size=8)
     leaves = jax.tree.leaves(specs)
-    # qwen reduced: 2 layers of GQA k/v — every leaf is a pool
-    assert all(l.shape[1:3] == (10, 8) for l in leaves)
+    # qwen reduced: 2 layers of GQA k/v — every leaf is a pool of pages
+    # stored (Hkv, PS, Dp)
+    assert all(l.shape[1:] == (10, cfg.n_kv_heads, 8, kv_lanes(cfg.hd))
+               for l in leaves)
     base = model2.cache_specs(3, 8)
     assert jax.tree_util.tree_structure(specs) == jax.tree_util.tree_structure(base)
 

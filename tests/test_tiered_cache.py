@@ -41,7 +41,7 @@ RULES = AxisRules(DEFAULT_RULES)
 PRESSURE = dict(batch_slots=3, max_len=32, page_size=4, n_pages=7)
 
 PAGED_FAMILIES = ["qwen2.5-3b", "deepseek-v3-671b", "mamba2-130m",
-                  "recurrentgemma-9b"]
+                  "recurrentgemma-9b", "granite-4.0-h-micro"]
 
 
 def _family_model(arch):
